@@ -97,6 +97,25 @@ def _git_sha() -> str:
     return "unknown"
 
 
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a CLI run.
+
+    ``$JAX_COMPILATION_CACHE_DIR``, when set, is used as it is (JAX reads
+    it itself); otherwise the cache lives at the fixed path
+    ``<repo>/.jax_cache``, so every run from this checkout finds what an
+    earlier one compiled.  Entry points call this; importing ``repro``
+    never does (the tests run without a cache).  Returns the directory.
+    """
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache",
+    )
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def main() -> None:
     args = sys.argv[1:]
     emit_json = "--json" in args
@@ -123,6 +142,7 @@ def main() -> None:
 
     from repro.kernels import default_backend
 
+    enable_compilation_cache()
     tiny = os.environ.get("REPRO_BENCH_TINY", "") not in ("", "0")
     git_sha = _git_sha()
     print("name,us_per_call,derived")

@@ -46,8 +46,10 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.coding import (
     bus_invert_partitions as _partitions,
@@ -55,7 +57,6 @@ from repro.core.coding import (
     sign_magnitude_encode_bytes,
 )
 
-from .backend import default_backend
 from .psu import _popcount_bits, _rank_from_keys
 
 __all__ = [
@@ -176,6 +177,21 @@ def max_partitions(
     )
 
 
+def _shift_rows(a: jax.Array, s: int) -> jax.Array:
+    """``a`` moved ``s`` rows down along axis 0, zero-filled at the top."""
+    return jnp.concatenate([jnp.zeros((s,) + a.shape[1:], a.dtype), a[:-s]])
+
+
+def _prefix_xor(a: jax.Array) -> jax.Array:
+    """Inclusive prefix XOR along axis 0 in log2(T) shift-and-combine
+    steps (Mosaic has no scan primitive; exact for any integer values)."""
+    s = 1
+    while s < a.shape[0]:
+        a = a ^ _shift_rows(a, s)
+        s *= 2
+    return a
+
+
 def _bus_invert_bits(hd: jax.Array, lbits: int) -> tuple[jax.Array, jax.Array]:
     """Invert-line states for both entry branches from pairwise data HDs.
 
@@ -183,24 +199,90 @@ def _bus_invert_bits(hd: jax.Array, lbits: int) -> tuple[jax.Array, jax.Array]:
     groups.  The sequential decision v_t = [2*HD(d_t, w_{t-1}) > L] obeys
     v_t = tie_t ? 0 : h_t ^ v_{t-1} (h_t = [2*HD_t > L], tie_t =
     [2*HD_t == L]), which is a prefix-XOR with resets at ties — evaluated
-    here with one cumsum and one cummax instead of a sequential scan.
-    Returns (v0, v1), both (T, P), for entry states v_0 = 0 and v_0 = 1.
+    here as a log-step segmented scan over (reset, xor) pairs instead of a
+    sequential one.  Returns (v0, v1), both (T, P), for entry states
+    v_0 = 0 and v_0 = 1.
     """
-    tm1, npart = hd.shape
-    h = (2 * hd > lbits).astype(jnp.int32)
-    tie = (2 * hd == lbits).astype(jnp.int32)
-    xpre = jnp.cumsum(h, axis=0) & 1  # X_t = h_1 ^ ... ^ h_t
-    tpos = lax.broadcasted_iota(jnp.int32, (tm1, npart), 0) + 1
-    packed = jnp.where(tie == 1, 2 * tpos + xpre, 0)  # (t, X_t) at ties
-    cmax = lax.cummax(packed, axis=0)  # carries the most recent tie
-    xr = jnp.where(cmax > 0, cmax & 1, 0)  # X at the last tie (else 0)
+    npart = hd.shape[1]
+    x = (2 * hd > lbits).astype(jnp.int32)  # h_t (never set at a tie)
+    r = (2 * hd == lbits).astype(jnp.int32)  # a reset has happened
+    s = 1
+    while s < hd.shape[0]:
+        x = jnp.where(r == 1, x, x ^ _shift_rows(x, s))
+        r = r | _shift_rows(r, s)
+        s *= 2
     zeros = jnp.zeros((1, npart), jnp.int32)
-    v0 = jnp.concatenate([zeros, xpre ^ xr], axis=0)
+    v0 = jnp.concatenate([zeros, x], axis=0)
     # no tie yet -> the entry bit still propagates: v1 = v0 ^ [no tie <= t]
-    notie = jnp.concatenate(
-        [zeros + 1, (cmax == 0).astype(jnp.int32)], axis=0
+    return v0, v0 ^ jnp.concatenate([zeros + 1, 1 - r], axis=0)
+
+
+def _onehot_dot(a: jax.Array, sel: jax.Array, dims) -> jax.Array:
+    """Exact integer contraction of ``a`` with a 0/1 selector on the MXU.
+
+    Every operand here is a small integer (bytes, counts, indices), so an
+    f32 product at HIGHEST precision is exact; the result is int32.
+    """
+    return lax.dot_general(
+        a.astype(jnp.float32),
+        sel.astype(jnp.float32),
+        dimension_numbers=(dims, ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=lax.Precision.HIGHEST,
+    ).astype(jnp.int32)
+
+
+def _group_matrix(lanes: int, pw: int, per_lane: int = 1) -> jax.Array:
+    """(lanes*per_lane, lanes//pw) 0/1 membership of lane-groups of ``pw``
+    lanes (``per_lane`` columns per lane, e.g. 8 wires), built from iota so
+    a Pallas kernel body can use it."""
+    shape = (lanes * per_lane, lanes // pw)
+    col = lax.broadcasted_iota(jnp.int32, shape, 0) // (pw * per_lane)
+    return (col == lax.broadcasted_iota(jnp.int32, shape, 1)).astype(
+        jnp.int32
     )
-    return v0, v0 ^ notie
+
+
+def _slot_of_position(
+    q: jax.Array, ln: int, flits: int, pack: str, column_major: bool
+) -> jax.Array:
+    """Packet slot feeding flit position ``q = f*ln + l`` of one side.
+
+    'row' packing fills flit f from slots f*ln..f*ln+ln-1; 'lane' packing
+    puts slot l*F + f on lane l of flit f.  The 'column_major' ordering is
+    the fixed transpose of the (F, L) packet view composed in front: slot
+    (l*F + f) carries element (f*L + l).
+    """
+    slot = q if pack == "row" else (q % ln) * flits + q // ln
+    if column_major:
+        slot = (slot % flits) * ln + slot // flits
+    return slot
+
+
+def _pack_side(values, ln, flits, pack, column_major):
+    """(BP, F*ln) ordered packet payloads -> (BP*F, ln) flit rows.
+
+    The packing permutation is one 0/1 selector product (skipped when it
+    is the identity), then static lane slices stacked flit-major — the
+    only relayouts Mosaic needs (no minor-dim split or 3-D transpose).
+    """
+    bp, n = values.shape
+    q = np.arange(n)
+    if not np.array_equal(
+        _slot_of_position(q, ln, flits, pack, column_major), q
+    ):
+        shape = (n, n)
+        src = _slot_of_position(
+            lax.broadcasted_iota(jnp.int32, shape, 1), ln, flits, pack,
+            column_major,
+        )
+        sel = lax.broadcasted_iota(jnp.int32, shape, 0) == src
+        values = _onehot_dot(values, sel, ((1,), (0,)))
+    if flits == 1:
+        return values
+    return jnp.stack(
+        [values[:, f * ln:(f + 1) * ln] for f in range(flits)], axis=1
+    ).reshape(bp * flits, ln)
 
 
 def _axes_block(
@@ -258,14 +340,36 @@ def _axes_block(
     valid = jnp.minimum(jnp.int32(rows), remaining_rows)
     row_idx = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
     bmask = (row_idx[1:] < valid).astype(jnp.int32)  # (rows-1, 1) boundaries
+    last_onehot = (row_idx == valid - 1).astype(jnp.int32)  # last valid row
+
+    def _edges(arr):  # (rows, L) -> (2, L): first row, last valid row
+        last = (arr * last_onehot).sum(axis=0, keepdims=True)
+        return jnp.concatenate([arr[:1], last], axis=0)
+
+    def _pad_lanes(arr, width):  # zero-pad the minor dim up to ``width``
+        if arr.shape[-1] == width:
+            return arr
+        fill = jnp.zeros(arr.shape[:-1] + (width - arr.shape[-1],), arr.dtype)
+        return jnp.concatenate([arr, fill], axis=-1)
+
+    def _sides(arr):  # (T, lanes) -> [input-side sum, weight-side sum, 0]
+        zero = jnp.int32(0)
+        return jnp.stack([
+            arr[:, :split_lanes].sum() if split_lanes else zero,
+            arr[:, split_lanes:].sum() if split_lanes < lanes else zero,
+            zero,
+        ])
 
     if act_on:
         nwires = lanes * 8 + pmax
-        bit_iota = lax.broadcasted_iota(jnp.int32, (1, 1, 8), 2)
+        lane_to_wire = _group_matrix(lanes, 1, 8)  # (lanes*8, lanes)
+        bit_of_wire = lax.broadcasted_iota(jnp.int32, (1, lanes * 8), 1) % 8
 
-        def _wire_bits(arr):  # (T, L) bytes -> (T, L*8) bits, LSB first
-            bits = (arr[:, :, None] >> bit_iota) & 1
-            return bits.reshape(arr.shape[0], arr.shape[1] * 8)
+        def _wire_bits(arr):  # (T, lanes) bytes -> (T, lanes*8) bits
+            # wire = lane*8 + bit, LSB first: copy each byte onto its 8
+            # wires with one selector product, then pick each wire's bit
+            per_wire = _onehot_dot(arr, lane_to_wire, ((1,), (1,)))
+            return (per_wire >> bit_of_wire) & 1
 
         rmask = (row_idx < valid).astype(jnp.int32)  # (rows, 1) levels
         # the boundary INTO local row i toggles inside row i's window
@@ -275,26 +379,12 @@ def _axes_block(
         win_iota = lax.broadcasted_iota(
             jnp.int32, (rows - 1, num_windows), 1
         )
-        win_onehot = (bwin == win_iota).astype(jnp.float32)
+        win_onehot = (bwin == win_iota).astype(jnp.int32)
 
         def _scatter(toggles):  # (rows-1, W) 0/1 -> (NW, W) window counts
-            return lax.dot_general(
-                win_onehot,
-                toggles.astype(jnp.float32),
-                dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ).astype(jnp.int32)
+            return _onehot_dot(win_onehot, toggles, ((0,), (0,)))
 
         acts, ones_rows = [], []
-
-    def _last_valid(arr):  # (rows, L) -> (L,): the row at index valid-1
-        onehot = (row_idx == valid - 1).astype(jnp.int32)
-        return (arr * onehot).sum(axis=0)
-
-    def _flit(values, ln):
-        if pack == "lane":
-            return values.reshape(bp, ln, flits).transpose(0, 2, 1)
-        return values.reshape(bp, flits, ln)
 
     # --- popcount stage: ONCE per block, shared by every bucketing
     # (computed lazily — identity-ordering launches skip it entirely) ---
@@ -308,6 +398,7 @@ def _axes_block(
             continue
         key_name, k, descending = cfg.ordering
         order = rank = None
+        xs, ws = x, w
         if key_name in ("acc", "app"):
             # --- bucket encoder + shared rank machinery (psu.py) ---
             if pc is None:
@@ -318,43 +409,43 @@ def _axes_block(
                 key, nb = (pc * k) // (width + 1), k
             if descending:
                 key = (nb - 1) - key
-            rank = _rank_from_keys(key, nb)
+            rank = _rank_from_keys(key)
             # --- reorder: one permutation-matrix MXU product yields the
             # ordered payloads (and, in emit_stream mode, `order` = the
             # permuted iota) in a single contraction (DESIGN.md §3.2) ---
             iota_j = lax.broadcasted_iota(jnp.int32, (bp, n, n), 2)
-            perm = (rank[:, :, None] == iota_j).astype(jnp.float32)
-            rows_payload = [x, w]
+            perm = rank[:, :, None] == iota_j
+            rows_payload = [x, w] if weight_lanes else [x]
             if emit_stream:
                 iota_i = lax.broadcasted_iota(jnp.int32, (bp, n), 1)
-                rows_payload = [iota_i, x, w]
-            payload = jnp.stack(rows_payload, axis=1).astype(jnp.float32)
+                rows_payload = [iota_i] + rows_payload
             moved = lax.dot_general(
-                payload,
-                perm,
+                jnp.stack(rows_payload, axis=1).astype(jnp.float32),
+                perm.astype(jnp.float32),
                 dimension_numbers=(((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
-            ).astype(jnp.int32)  # (BP, 2|3, N)
-            xs, ws = moved[:, -2, :], moved[:, -1, :]
+                precision=lax.Precision.HIGHEST,
+            ).astype(jnp.int32)  # (BP, 1|2|3, N)
+            # static row picks (a negative index would lower as a
+            # dynamic_slice, which Mosaic has no rule for)
+            picked = [
+                lax.index_in_dim(moved, i, axis=1, keepdims=False)
+                for i in range(len(rows_payload))
+            ]
+            if weight_lanes:
+                xs, ws = picked[-2:]
+            else:
+                xs = picked[-1]
             if emit_stream:
-                order = moved[:, 0, :]
-        elif key_name == "column_major":
-            # fixed layout permutation — output position (l*F + f) carries
-            # input element (f*L + l): a transpose of the (F, L) packet view
-            xs = x.reshape(bp, flits, input_lanes).transpose(0, 2, 1)
-            xs = xs.reshape(bp, n)
-            ws = w.reshape(bp, flits, input_lanes).transpose(0, 2, 1)
-            ws = ws.reshape(bp, n)
-        else:  # 'none'
-            xs, ws = x, w
+                order = picked[0]
+        cm = key_name == "column_major"
+        stream = _pack_side(xs, input_lanes, flits, pack, cm)
         if weight_lanes:
-            flit_block = jnp.concatenate(
-                [_flit(xs, input_lanes), _flit(ws, weight_lanes)], axis=-1
+            stream = jnp.concatenate(
+                [stream, _pack_side(ws, weight_lanes, flits, pack, cm)],
+                axis=-1,
             )
-        else:
-            flit_block = _flit(xs, input_lanes)
-        stream = flit_block.reshape(rows, lanes)
-        streams[cfg.ordering] = stream
+        streams[cfg.ordering] = stream  # (rows, lanes)
         if emit_stream and cfg.ordering == configs[0].ordering:
             emitted = (order, rank, stream)
 
@@ -372,115 +463,103 @@ def _axes_block(
             else:
                 wire = stream
             flips = _popcount_bits(wire[1:] ^ wire[:-1], 8) * bmask
-            row = jnp.stack(
-                [
-                    flips[:, :split_lanes].sum(),
-                    flips[:, split_lanes:].sum()
-                    if split_lanes < lanes
-                    else jnp.int32(0),
-                    jnp.int32(0),
-                ]
-            )
-            part = jnp.broadcast_to(row, (2, 1, 3))
-            edge = jnp.stack([wire[0], _last_valid(wire)])  # (2, lanes)
+            part = jnp.broadcast_to(_sides(flips), (2, 1, 3))
             bts.append(jnp.pad(part, ((0, 0), (0, pmax - 1), (0, 0))))
-            edge_rows.append(jnp.broadcast_to(edge, (2, 2, lanes)))
+            edge_rows.append(jnp.broadcast_to(_edges(wire), (2, 2, lanes)))
             inv_rows.append(zero_inv)
             if act_on:
                 tb = _wire_bits(wire[1:] ^ wire[:-1]) * bmask
-                act = jnp.pad(_scatter(tb), ((0, 0), (0, pmax)))
+                act = _pad_lanes(_scatter(tb), nwires)
                 acts.append(jnp.broadcast_to(act, (2, num_windows, nwires)))
-                ones_w = (_wire_bits(wire) * rmask).sum(axis=0)
-                ones_rows.append(jnp.broadcast_to(
-                    jnp.pad(ones_w, (0, pmax)), (2, nwires)
-                ))
+                ones_w = (_wire_bits(wire) * rmask).sum(axis=0, keepdims=True)
+                ones_rows.append(
+                    jnp.broadcast_to(_pad_lanes(ones_w, nwires), (2, nwires))
+                )
 
         elif cfg.codec == "transition":
             # wire_t ^ wire_{t-1} == data_t: boundary flips = data popcount
-            ppc = _popcount_bits(stream, 8)
-            contrib = ppc[1:] * bmask
-            row = jnp.stack(
-                [
-                    contrib[:, :split_lanes].sum(),
-                    contrib[:, split_lanes:].sum()
-                    if split_lanes < lanes
-                    else jnp.int32(0),
-                    jnp.int32(0),
-                ]
-            )
-            part = jnp.broadcast_to(row, (2, 1, 3))
-            # edges carry DATA flits (the wrapper adds first-flit popcounts)
-            edge = jnp.stack([stream[0], _last_valid(stream)])
+            contrib = _popcount_bits(stream, 8)[1:] * bmask
+            part = jnp.broadcast_to(_sides(contrib), (2, 1, 3))
             bts.append(jnp.pad(part, ((0, 0), (0, pmax - 1), (0, 0))))
-            edge_rows.append(jnp.broadcast_to(edge, (2, 2, lanes)))
+            # edges carry DATA flits (the wrapper adds first-flit popcounts)
+            edge_rows.append(
+                jnp.broadcast_to(_edges(stream), (2, 2, lanes))
+            )
             inv_rows.append(zero_inv)
             if act_on:
                 # wire-bit toggle at boundary t == data bit of row t
                 tb = _wire_bits(stream[1:]) * bmask
-                act = jnp.pad(_scatter(tb), ((0, 0), (0, pmax)))
+                act = _pad_lanes(_scatter(tb), nwires)
                 acts.append(jnp.broadcast_to(act, (2, num_windows, nwires)))
                 # the wire LEVEL is the running data parity; slot 0 = time
                 # at 1 for a parity-0 entry, slot 1 = this block's parity
                 # (the wrapper flips slot 0 per the carried entry parity)
                 db = _wire_bits(stream) * rmask
-                par = jnp.cumsum(db, axis=0) & 1
-                ones_rows.append(jnp.stack([
-                    jnp.pad((par * rmask).sum(axis=0), (0, pmax)),
-                    jnp.pad(db.sum(axis=0) & 1, (0, pmax)),
+                par = _prefix_xor(db)
+                ones_rows.append(jnp.concatenate([
+                    _pad_lanes((par * rmask).sum(axis=0, keepdims=True), nwires),
+                    _pad_lanes(db.sum(axis=0, keepdims=True) & 1, nwires),
                 ]))
 
         else:  # bus_invert
             npart, pw = _partitions(lanes, cfg.partition)
             lbits = 8 * pw
-            d = stream.reshape(rows, npart, pw)
-            dpc = _popcount_bits(d[1:] ^ d[:-1], 8)  # (rows-1, npart, pw)
-            v0, v1 = _bus_invert_bits(dpc.sum(axis=-1), lbits)
-            # input/weight lane split inside each partition: global lane id
-            # part*pw + j < split_lanes (iota, not a captured constant)
-            lane_id = lax.broadcasted_iota(
-                jnp.int32, (npart, pw), 0
-            ) * pw + lax.broadcasted_iota(jnp.int32, (npart, pw), 1)
-            in_mask = (lane_id < split_lanes).astype(jnp.int32)
+            grp = _group_matrix(lanes, pw)  # (lanes, npart) lane -> group
+            dx = stream[1:] ^ stream[:-1]  # (rows-1, lanes)
+            dpc = _popcount_bits(dx, 8)
+            v0, v1 = _bus_invert_bits(
+                _onehot_dot(dpc, grp, ((1,), (0,))), lbits
+            )
+            # input/weight lane split: global lane id < split_lanes
+            in_mask = (
+                lax.broadcasted_iota(jnp.int32, (1, lanes), 1) < split_lanes
+            ).astype(jnp.int32)
+            ones_col = jnp.ones((rows - 1, 1), jnp.int32)
+
+            def _per_part(arr):  # (rows-1, lanes) -> (npart, 1) group sums
+                return _onehot_dot(
+                    grp, arr.sum(axis=0, keepdims=True), ((0,), (1,))
+                )
+
             parts, edges, inv_edges = [], [], []
             acts_b, ones_b = [], []
             if act_on:
-                dxr = (d[1:] ^ d[:-1]).reshape(rows - 1, lanes)
+                grp8 = _group_matrix(lanes, pw, 8)  # (lanes*8, npart)
             for v in (v0, v1):
                 e = v[1:] ^ v[:-1]  # (rows-1, npart) invert-line flips
-                lane_flips = jnp.where(e[:, :, None] == 1, 8 - dpc, dpc)
-                lane_flips = lane_flips * bmask[:, :, None]
-                bt_in = (lane_flips * in_mask).sum(axis=(0, 2))
-                bt_wg = (lane_flips * (1 - in_mask)).sum(axis=(0, 2))
-                aux = (e * bmask).sum(axis=0)
-                parts.append(jnp.stack([bt_in, bt_wg, aux], axis=-1))
-                wire = (d ^ (v[:, :, None] * 0xFF)).reshape(rows, lanes)
-                edges.append(jnp.stack([wire[0], _last_valid(wire)]))
-                inv_edges.append(jnp.stack([v[0], _last_valid(v)]))
+                e_lane = _onehot_dot(e, grp, ((1,), (1,)))  # (rows-1, lanes)
+                lane_flips = jnp.where(e_lane == 1, 8 - dpc, dpc) * bmask
+                parts.append(jnp.concatenate([
+                    _per_part(lane_flips * in_mask),
+                    _per_part(lane_flips * (1 - in_mask)),
+                    _onehot_dot(e * bmask, ones_col, ((0,), (0,))),
+                ], axis=1))  # (npart, 3)
+                wire = stream ^ (_onehot_dot(v, grp, ((1,), (1,))) * 0xFF)
+                edges.append(_edges(wire))
+                inv_edges.append(_pad_lanes(_edges(v), pmax))
                 if act_on:
                     # wire-bit toggle = data-bit toggle XOR its partition's
                     # invert-line flip; the invert line itself is a wire
-                    erep = jnp.broadcast_to(
-                        e[:, :, None], (rows - 1, npart, pw * 8)
-                    ).reshape(rows - 1, lanes * 8)
-                    tb = (_wire_bits(dxr) ^ erep) * bmask
-                    aux_t = jnp.pad(e * bmask, ((0, 0), (0, pmax - npart)))
+                    erep = _onehot_dot(e, grp8, ((1,), (1,)))
+                    tb = (_wire_bits(dx) ^ erep) * bmask
+                    aux_t = _pad_lanes(e * bmask, pmax)
                     acts_b.append(
                         _scatter(jnp.concatenate([tb, aux_t], axis=1))
                     )
                     ones_b.append(jnp.concatenate([
-                        (_wire_bits(wire) * rmask).sum(axis=0),
-                        jnp.pad((v * rmask).sum(axis=0), (0, pmax - npart)),
-                    ]))
+                        (_wire_bits(wire) * rmask).sum(axis=0, keepdims=True),
+                        _pad_lanes(
+                            (v * rmask).sum(axis=0, keepdims=True), pmax
+                        ),
+                    ], axis=1))
             bts.append(jnp.pad(
                 jnp.stack(parts), ((0, 0), (0, pmax - npart), (0, 0))
             ))
             edge_rows.append(jnp.stack(edges))
-            inv_rows.append(jnp.pad(
-                jnp.stack(inv_edges), ((0, 0), (0, 0), (0, pmax - npart))
-            ))
+            inv_rows.append(jnp.stack(inv_edges))
             if act_on:
                 acts.append(jnp.stack(acts_b))
-                ones_rows.append(jnp.stack(ones_b))
+                ones_rows.append(jnp.concatenate(ones_b))
 
     out = (jnp.stack(bts), jnp.stack(edge_rows), jnp.stack(inv_rows))
     if act_on:
@@ -503,8 +582,9 @@ def _bt_axes_kernel(*refs, **static):
     bp, n = x_ref.shape[1:]
     flits = n // static["input_lanes"]
     rows = jnp.int32(bp * flits)
-    remaining = valid_ref[0, 0] * flits - pl.program_id(1) * rows
-    start = base_ref[0, 0] + pl.program_id(1) * rows if activity else None
+    # per-link valid counts and the launch's base row live in SMEM
+    remaining = valid_ref[pl.program_id(0)] * flits - pl.program_id(1) * rows
+    start = base_ref[0] + pl.program_id(1) * rows if activity else None
     out = _axes_block(x_ref[0], w_ref[0], remaining, start, **static)
     bt_ref[0, 0] = out[0]
     edge_ref[0, 0] = out[1]
@@ -529,7 +609,7 @@ def bt_axes_pallas(
     pack: str = "lane",
     block_packets: int = 64,
     emit_stream: bool = False,
-    interpret: bool | None = None,
+    interpret: bool = False,
     window_rows: int = 0,
     num_windows: int = 0,
     base_row: jax.Array | None = None,
@@ -580,8 +660,6 @@ def bt_axes_pallas(
         block_packets=block_packets, emit_stream=emit_stream,
         num_windows=num_windows, window_rows=window_rows,
     )
-    if interpret is None:
-        interpret = default_backend() != "pallas"
     links, p, n = inputs.shape
     lanes = input_lanes + weight_lanes
     nc = len(configs)
@@ -604,15 +682,18 @@ def bt_axes_pallas(
         num_windows=num_windows,
     )
     pk_spec = pl.BlockSpec((1, block_packets, n), lambda l, g: (l, g, 0))
-    in_specs = [
-        pk_spec,
-        pk_spec,
-        pl.BlockSpec((1, 1), lambda l, g: (l, 0)),
-    ]
+    # inside shard_map the outputs vary over the same mesh axes as the links
+    vma = jax.typeof(inputs).vma
+
+    def _out(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    in_specs = [pk_spec, pk_spec, smem]
     out_shape = [
-        jax.ShapeDtypeStruct((links, gblocks, nc, 2, pmax, 3), jnp.int32),
-        jax.ShapeDtypeStruct((links, gblocks, nc, 2, 2, lanes), jnp.int32),
-        jax.ShapeDtypeStruct((links, gblocks, nc, 2, 2, pmax), jnp.int32),
+        _out((links, gblocks, nc, 2, pmax, 3), jnp.int32),
+        _out((links, gblocks, nc, 2, 2, lanes), jnp.int32),
+        _out((links, gblocks, nc, 2, 2, pmax), jnp.int32),
     ]
     out_specs = [
         pl.BlockSpec((1, 1, nc, 2, pmax, 3), lambda l, g: (l, g, 0, 0, 0, 0)),
@@ -621,12 +702,12 @@ def bt_axes_pallas(
     ]
     if activity:
         nwires = lanes * 8 + pmax
-        in_specs.append(pl.BlockSpec((1, 1), lambda l, g: (0, 0)))
+        in_specs.append(smem)
         out_shape += [
-            jax.ShapeDtypeStruct(
+            _out(
                 (links, gblocks, nc, 2, num_windows, nwires), jnp.int32
             ),
-            jax.ShapeDtypeStruct((links, gblocks, nc, 2, nwires), jnp.int32),
+            _out((links, gblocks, nc, 2, nwires), jnp.int32),
         ]
         out_specs += [
             pl.BlockSpec(
@@ -637,9 +718,9 @@ def bt_axes_pallas(
         ]
     if emit_stream:
         out_shape += [
-            jax.ShapeDtypeStruct((links, p, n), jnp.int32),
-            jax.ShapeDtypeStruct((links, p, n), jnp.int32),
-            jax.ShapeDtypeStruct((links, p * flits, lanes), jnp.int32),
+            _out((links, p, n), jnp.int32),
+            _out((links, p, n), jnp.int32),
+            _out((links, p * flits, lanes), jnp.int32),
         ]
         out_specs += [
             pk_spec,
@@ -651,11 +732,11 @@ def bt_axes_pallas(
     args = [
         inputs.astype(jnp.int32),
         weights.astype(jnp.int32),
-        valid.astype(jnp.int32).reshape(links, 1),
+        valid.astype(jnp.int32),
     ]
     if activity:
         base = jnp.int32(0) if base_row is None else base_row
-        args.append(jnp.asarray(base, jnp.int32).reshape(1, 1))
+        args.append(jnp.asarray(base, jnp.int32).reshape(1))
     return pl.pallas_call(
         kern,
         grid=grid,

@@ -5,7 +5,8 @@ paper's evaluation workhorse; at framework scale we run it over multi-GB
 modeled traffic (weights, activations, collective payloads), so it gets a
 kernel.  The wrapper presents the stream twice (rows [0, T-1) and rows
 [1, T)) so each grid step reduces one (R, L) block of XOR popcounts with no
-cross-block carry; per-block partials land in a (G,) output reduced by the
+cross-block carry; per-block partials land in a (G, 1, 1) output (a block
+whose two minor dims equal the array's, as Mosaic requires) reduced by the
 caller.  Memory-bound by design: one pass over the stream, 8 ops/byte.
 """
 
@@ -17,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .backend import default_backend
 from .psu import _popcount_bits
 
 __all__ = ["bt_count_pallas", "bt_count_compiled"]
@@ -27,7 +27,7 @@ def _bt_kernel(a_ref, b_ref, out_ref, *, width: int):
     a = a_ref[...].astype(jnp.int32)
     b = b_ref[...].astype(jnp.int32)
     flips = jnp.bitwise_xor(a, b)
-    out_ref[0] = _popcount_bits(flips, width).sum()
+    out_ref[0] = _popcount_bits(flips, width).sum(keepdims=True)
 
 
 def bt_count_pallas(
@@ -35,7 +35,7 @@ def bt_count_pallas(
     *,
     width: int = 8,
     block_rows: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Total bit transitions of a (T, L) flit stream (int32 scalar).
 
@@ -43,8 +43,6 @@ def bt_count_pallas(
     rows are padded (with zeros on *both* shifted views, so pads contribute
     zero) to a multiple of ``block_rows``.
     """
-    if interpret is None:
-        interpret = default_backend() != "pallas"
     t, lanes = stream.shape
     if t < 2:
         return jnp.int32(0)
@@ -62,8 +60,8 @@ def bt_count_pallas(
         kern,
         grid=grid,
         in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(grid, jnp.int32),
+        out_specs=pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(grid + (1, 1), jnp.int32),
         interpret=interpret,
     )(a, b)
     return partials.sum()

@@ -129,10 +129,7 @@ def pallas_launch_count(fn, *args) -> int:
     compiled backend would trivially trace to zero).  An explicit
     ``backend=``/``interpret=`` inside ``fn`` still wins.
     """
-    try:  # jaxpr types' public home since jax 0.4.33
-        from jax.extend import core as jcore
-    except ImportError:  # older releases
-        from jax import core as jcore
+    from jax.extend import core as jcore
 
     def walk(jaxpr) -> int:
         n = 0
@@ -177,7 +174,7 @@ def _psu_sort(
     x = jnp.pad(packets.astype(jnp.int32), ((0, pad), (0, 0)))
     if backend == "compiled":
         order, rank = psu_sort_compiled(
-            x, width=width, k=k, descending=descending
+            x, width=width, k=k, descending=descending, block_packets=bp
         )
     else:
         order, rank = psu_sort_pallas(
@@ -271,6 +268,19 @@ class LinkActivity(NamedTuple):
     ones: jax.Array  # (L, WIRES)
 
 
+def _vary_like(tree, like):
+    """``tree`` made varying over the manual mesh axes ``like`` varies over.
+
+    Inside ``shard_map`` the per-link data varies over the mesh axis, and a
+    ``lax.scan`` carry must keep one type from step to step, so zero
+    initial carries are cast to varying (a no-op outside ``shard_map``).
+    """
+    vma = tuple(sorted(jax.typeof(like).vma))
+    if not vma:
+        return tree
+    return jax.tree.map(lambda c: lax.pcast(c, vma, to="varying"), tree)
+
+
 def _axes_carry(nl: int, configs, lanes: int, activity: bool = False):
     """The zero inter-chunk fold carry: nothing transmitted yet."""
     pmax = max_partitions(configs, lanes)
@@ -330,7 +340,10 @@ def _fold_axes(
     lanes = edges.shape[-1]
     pmax = partials.shape[-2]
     if carry is None:
-        carry = _axes_carry(nl, configs, lanes, activity=activity is not None)
+        carry = _vary_like(
+            _axes_carry(nl, configs, lanes, activity=activity is not None),
+            partials,
+        )
     started0 = carry["started"]
     has = (valid_rows > 0).astype(jnp.int32)
     # block g holds >= 1 valid row of this link
@@ -675,6 +688,7 @@ def _dispatch_axes(
             jnp.zeros((links, len(configs), nw, nwires), jnp.int32),
             jnp.zeros((links, len(configs), nwires), jnp.int32),
         )
+    state0 = _vary_like(state0, x)
     state, _ = lax.scan(step, state0, (xb, wb, cvalid, bases))
     if wlen is None:
         return state[1]
@@ -983,69 +997,49 @@ def bt_count_axes(
         )
 
 
-def bt_count_axes_sharded(
+@partial(
+    jax.jit,
+    static_argnames=(
+        "mesh",
+        "configs",
+        "width",
+        "input_lanes",
+        "weight_lanes",
+        "split_lanes",
+        "pack",
+        "block_packets",
+        "backend",
+        "chunk_packets",
+        "activity_windows",
+    ),
+)
+def _bt_count_axes_sharded(
     inputs: jax.Array,
-    weights: jax.Array | None = None,
-    valid: jax.Array | Sequence[int] | None = None,
-    configs: tuple[CodecVariant, ...] = (CodecVariant(),),
-    width: int = 8,
-    input_lanes: int = 8,
-    weight_lanes: int | None = None,
-    split_lanes: int | None = None,
-    pack: str = "lane",
-    block_packets: int = 64,
-    interpret: bool | None = None,
-    backend: str | None = None,
-    chunk_packets: int | None = None,
-    activity_windows: int | None = None,
-    devices: Sequence[jax.Device] | None = None,
-) -> jax.Array:
-    """:func:`bt_count_axes` with the LINK axis sharded across devices.
+    weights: jax.Array,
+    valid: jax.Array,
+    *,
+    mesh,
+    configs: tuple[CodecVariant, ...],
+    width: int,
+    input_lanes: int,
+    weight_lanes: int,
+    split_lanes: int | None,
+    pack: str,
+    block_packets: int,
+    backend: str,
+    chunk_packets: int | None,
+    activity_windows: int | None,
+):
+    from jax.sharding import PartitionSpec
 
-    ``shard_map`` (via ``repro.compat``) splits the links of a NoC grid
-    over a 1-D device mesh; each device measures its shard with the same
-    launch + fold as the unsharded path, scatters it into the full-table
-    layout and a ``psum`` assembles the replicated (L, C, 3) BT table.
-    Links are padded to a device multiple with ``valid = 0`` links, whose
-    rows the unified masking convention zeroes — so the padding is exact,
-    not approximate.  Per-link results are bit-identical to the unsharded
-    entry point (each link's fold never crosses the shard boundary).
-    """
-    if inputs.ndim != 3:
-        raise ValueError(f"expected (L, P, N) packets, got {inputs.shape}")
-    from jax.sharding import Mesh, PartitionSpec
-
-    from repro.compat import shard_map
-
-    backend = resolve_backend(backend, interpret)
-    devices = list(jax.devices() if devices is None else devices)
-    nd = len(devices)
-    weights, weight_lanes = _paired(inputs, weights, weight_lanes, input_lanes)
-    links, p, n = inputs.shape
-    nc = len(configs := tuple(configs))
-    lanes = input_lanes + weight_lanes
-    if links == 0 or p == 0:
-        bt = jnp.zeros((links, nc, 3), jnp.int32)
-        if activity_windows is None:
-            return bt
-        nwires = lanes * 8 + max_partitions(configs, lanes)
-        nw = 0 if p == 0 else -(-(p * (n // input_lanes)) // activity_windows)
-        return AxesActivity(
-            bt,
-            jnp.zeros((links, nc, nw, nwires), jnp.int32),
-            jnp.zeros((links, nc, nwires), jnp.int32),
-        )
-    if valid is None:
-        valid = jnp.full((links,), p, jnp.int32)
-    else:
-        valid = jnp.minimum(jnp.asarray(valid, jnp.int32), p)
+    links = inputs.shape[0]
+    nd = mesh.devices.size
     lpad = (-links) % nd
     x = jnp.pad(inputs.astype(jnp.int32), ((0, lpad), (0, 0), (0, 0)))
     w = jnp.pad(weights.astype(jnp.int32), ((0, lpad), (0, 0), (0, 0)))
     v = jnp.pad(valid, (0, lpad))
     ltot = links + lpad
     shard = ltot // nd
-    mesh = Mesh(np.asarray(devices), ("links",))
 
     def _assemble(arr):
         # scatter this shard's rows into the full-link layout and psum
@@ -1074,18 +1068,91 @@ def bt_count_axes_sharded(
         return AxesActivity(*(_assemble(o) for o in out))
 
     spec = PartitionSpec("links")
-    with _probe("bt_count_axes_sharded", backend,
-                shape=(ltot, int(p), int(n)), configs=nc, width=width,
-                devices=nd, activity=activity_windows is not None):
-        out = shard_map(
-            local,
-            mesh=mesh,
-            in_specs=(spec, spec, spec),
-            out_specs=PartitionSpec(),
-        )(x, w, v)
+    out = jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=PartitionSpec(),
+    )(x, w, v)
     if activity_windows is None:
         return out[:links]
     return AxesActivity(*(o[:links] for o in out))
+
+
+def bt_count_axes_sharded(
+    inputs: jax.Array,
+    weights: jax.Array | None = None,
+    valid: jax.Array | Sequence[int] | None = None,
+    configs: tuple[CodecVariant, ...] = (CodecVariant(),),
+    width: int = 8,
+    input_lanes: int = 8,
+    weight_lanes: int | None = None,
+    split_lanes: int | None = None,
+    pack: str = "lane",
+    block_packets: int = 64,
+    interpret: bool | None = None,
+    backend: str | None = None,
+    chunk_packets: int | None = None,
+    activity_windows: int | None = None,
+    devices: Sequence[jax.Device] | None = None,
+) -> jax.Array:
+    """:func:`bt_count_axes` with the LINK axis sharded across devices.
+
+    ``jax.shard_map`` splits the links of a NoC grid
+    over a 1-D device mesh; each device measures its shard with the same
+    launch + fold as the unsharded path, scatters it into the full-table
+    layout and a ``psum`` assembles the replicated (L, C, 3) BT table.
+    Links are padded to a device multiple with ``valid = 0`` links, whose
+    rows the unified masking convention zeroes — so the padding is exact,
+    not approximate.  Per-link results are bit-identical to the unsharded
+    entry point (each link's fold never crosses the shard boundary).  Like
+    every entry point the sharded program is jit-compiled once per shape
+    and configuration (the interpreter backend runs it eagerly).
+    """
+    if inputs.ndim != 3:
+        raise ValueError(f"expected (L, P, N) packets, got {inputs.shape}")
+    from jax.sharding import Mesh
+
+    backend = resolve_backend(backend, interpret)
+    devices = list(jax.devices() if devices is None else devices)
+    weights, weight_lanes = _paired(inputs, weights, weight_lanes, input_lanes)
+    links, p, n = inputs.shape
+    nc = len(configs := tuple(configs))
+    lanes = input_lanes + weight_lanes
+    if links == 0 or p == 0:
+        bt = jnp.zeros((links, nc, 3), jnp.int32)
+        if activity_windows is None:
+            return bt
+        nwires = lanes * 8 + max_partitions(configs, lanes)
+        nw = 0 if p == 0 else -(-(p * (n // input_lanes)) // activity_windows)
+        return AxesActivity(
+            bt,
+            jnp.zeros((links, nc, nw, nwires), jnp.int32),
+            jnp.zeros((links, nc, nwires), jnp.int32),
+        )
+    if valid is None:
+        valid = jnp.full((links,), p, jnp.int32)
+    else:
+        valid = jnp.minimum(jnp.asarray(valid, jnp.int32), p)
+    with _probe("bt_count_axes_sharded", backend,
+                shape=(int(links), int(p), int(n)), configs=nc, width=width,
+                devices=len(devices), activity=activity_windows is not None):
+        return _entry(_bt_count_axes_sharded, backend)(
+            inputs,
+            weights,
+            valid,
+            mesh=Mesh(np.asarray(devices), ("links",)),
+            configs=configs,
+            width=width,
+            input_lanes=input_lanes,
+            weight_lanes=weight_lanes,
+            split_lanes=split_lanes,
+            pack=pack,
+            block_packets=block_packets,
+            backend=backend,
+            chunk_packets=chunk_packets,
+            activity_windows=activity_windows,
+        )
 
 
 @partial(

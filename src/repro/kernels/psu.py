@@ -8,18 +8,16 @@ hardware dataflow of Fig. 1 stage-for-stage on TPU vector units
                       4-bit LUT + adder tree,
   bucket encoder   -> integer multiply/divide (APP only; compiled away for
                       ACC exactly as the paper's synthesis prunes the LUT),
-  one-hot + histogram + prefix sum -> lane cumsums over a (BP, N, K) one-hot
-                      tensor (the hardware prefix-sum stage is literally the
-                      cumsum over the bucket axis),
-  index mapping    -> rank = starts[key] + #earlier-equal, then the scatter
-                      SRAM write becomes a one-hot compare + weighted sum
-                      (MXU/VPU-friendly; no random-access writes).
+  histogram + prefix sum + index mapping
+                   -> rank = #{lower-bucket elements} + #{earlier equal
+                      elements}, one (BP, N, N) compare + lane reduction
+                      (the hardware's prefix-sum addresses, with no scan),
+                      then the scatter SRAM write becomes a one-hot compare
+                      + weighted sum (no random-access writes).
 
-Block shapes: packets are (BP, N) int32 in VMEM; the (BP, N, K) and
-(BP, N, N) intermediates bound VMEM use, so BP defaults to 64 packets
-(N=64, K<=9: ~3.3 MB of int32 temporaries, well inside a v5e core's VMEM).
-On real TPU the N axis should be padded to the 128-lane boundary; the
-wrapper in ``ops.py`` does this transparently.
+Block shapes: packets are (BP, N) int32 in VMEM; the (BP, N, N)
+intermediates bound VMEM use, so BP defaults to 64 packets (N=64: 1 MiB
+per int32 temporary, well inside a v5e core's VMEM).
 """
 
 from __future__ import annotations
@@ -30,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-from .backend import default_backend
 
 __all__ = ["psu_sort_pallas", "psu_sort_compiled"]
 
@@ -52,33 +48,32 @@ def _popcount_bits(x: jax.Array, width: int) -> jax.Array:
     return v & jnp.int32(0x1F)
 
 
-def _rank_from_keys(key: jax.Array, nb: int) -> jax.Array:
-    """Stages 2-3 of the PSU on one (BP, N) int32 key block: one-hot /
-    histogram / prefix-sum, then index mapping.
+def _rank_from_keys(key: jax.Array) -> jax.Array:
+    """Stages 2-3 of the PSU on one (BP, N) int32 key block: the stable
+    counting-sort output address of every element.
 
-    Factored out of :func:`_rank_block` so the multi-axis BT kernel
-    (``axes.py``) can derive several bucketings from ONE popcount pass
-    without duplicating the counting-sort machinery.  Returns the
-    (BP, N) int32 ``rank`` (stable counting-sort output addresses).
+    rank_i = #{j : key_j < key_i} + #{j < i : key_j == key_i} — the
+    histogram prefix sum (elements in lower buckets) plus the earlier-equal
+    count, evaluated as one (BP, N, N) comparison and a lane reduction, so
+    the kernel needs no in-kernel scan.  Factored out of
+    :func:`_rank_block` so the multi-axis BT kernel (``axes.py``) can
+    derive several bucketings from ONE popcount pass.
     """
     bp, n = key.shape
-
-    # --- one-hot / histogram / prefix-sum stages ---
-    iota_k = lax.broadcasted_iota(jnp.int32, (bp, n, nb), 2)
-    onehot = (key[:, :, None] == iota_k).astype(jnp.int32)  # (BP, N, K)
-    within = jnp.cumsum(onehot, axis=1) - onehot  # earlier-equal count
-    hist = onehot.sum(axis=1)  # (BP, K)
-    starts = jnp.cumsum(hist, axis=1) - hist  # exclusive prefix sum
-
-    # --- index mapping stage ---
-    return ((within + starts[:, None, :]) * onehot).sum(axis=2)  # (BP, N)
+    ki = key[:, :, None]  # element i
+    kj = key[:, None, :]  # every other element j
+    i = lax.broadcasted_iota(jnp.int32, (bp, n, n), 1)
+    j = lax.broadcasted_iota(jnp.int32, (bp, n, n), 2)
+    before = (kj < ki) | ((kj == ki) & (j < i))
+    return before.astype(jnp.int32).sum(axis=2)
 
 
 def _rank_block(
     x: jax.Array, *, width: int, k: int | None, descending: bool
 ) -> jax.Array:
     """Stages 1-3 of the PSU on one (BP, N) int32 block: popcount (+ APP
-    bucket encoder), one-hot / histogram / prefix-sum, index mapping.
+    bucket encoder), then the counting-sort address
+    (:func:`_rank_from_keys`).
 
     Shared between the standalone sort kernel below and the multi-axis BT
     core (``axes.py``), so the key derivation cannot drift between them.
@@ -93,7 +88,7 @@ def _rank_block(
         key, nb = (p * k) // (width + 1), k
     if descending:
         key = (nb - 1) - key
-    return _rank_from_keys(key, nb)
+    return _rank_from_keys(key)
 
 
 def _psu_kernel(
@@ -121,7 +116,7 @@ def psu_sort_pallas(
     k: int | None = None,
     descending: bool = False,
     block_packets: int = 64,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Sort indices for a batch of packets with the PSU kernel.
 
@@ -138,8 +133,6 @@ def psu_sort_pallas(
     Returns:
       (order, rank) int32 arrays of shape (P, N).
     """
-    if interpret is None:
-        interpret = default_backend() != "pallas"
     p, n = packets.shape
     if p % block_packets != 0:
         raise ValueError(f"P={p} not a multiple of block_packets={block_packets}")
@@ -166,17 +159,22 @@ def psu_sort_compiled(
     width: int = 8,
     k: int | None = None,
     descending: bool = False,
+    block_packets: int = 64,
 ) -> tuple[jax.Array, jax.Array]:
     """The compiled (pure-jnp) backend of the PSU sort.
 
-    Runs the SAME rank derivation as the kernel (:func:`_rank_block`) on
-    the whole (P, N) batch at once — every stage is per-packet, so block
-    granularity cannot change results — and inverts the rank permutation
-    with an argsort instead of the kernel's one-hot scatter (identical
-    output on a permutation).  Bit-exact with the kernel.
+    Runs the SAME rank derivation as the kernel (:func:`_rank_block`) one
+    (``block_packets``, N) block at a time (``lax.map``, so the (BP, N, N)
+    compare never materializes for more than one block) and inverts the
+    rank permutation with an argsort instead of the kernel's one-hot
+    scatter (identical output on a permutation).  Bit-exact with the
+    kernel.  P must be a multiple of ``block_packets``.
     """
-    rank = _rank_block(
-        packets.astype(jnp.int32), width=width, k=k, descending=descending
-    )
+    p, n = packets.shape
+    blocks = packets.astype(jnp.int32).reshape(p // block_packets, block_packets, n)
+    rank = lax.map(
+        lambda b: _rank_block(b, width=width, k=k, descending=descending),
+        blocks,
+    ).reshape(p, n)
     order = jnp.argsort(rank, axis=-1).astype(jnp.int32)
     return order, rank

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 
 Carry = jax.Array
 
@@ -74,14 +73,9 @@ def pipeline_apply(
         mine = jnp.where(sid == last, result, jnp.zeros_like(result))
         return lax.psum(mine, axis)
 
-    # check_rep=False: the activation-tagging primitive (checkpoint_name)
-    # has no replication rule in some jax versions; replication of the
-    # output is guaranteed by the masked-psum broadcast above.
-    kwargs = dict(mesh=mesh, in_specs=(P(axis), P()), out_specs=P())
-    try:
-        fn = shard_map(per_device, check_rep=False, **kwargs)
-    except TypeError:  # newer jax renamed/removed check_rep
-        fn = shard_map(per_device, **kwargs)
+    fn = jax.shard_map(
+        per_device, mesh=mesh, in_specs=(P(axis), P()), out_specs=P()
+    )
     return fn(stage_params, x_micro)
 
 

@@ -115,8 +115,8 @@ class TxPipeline:
       power: energy model for ``LinkReport.energy_pj`` (default paper model).
       fused: force (True) or forbid (False) the fused kernel; None = use it
         whenever the spec allows.
-      interpret: Pallas interpret-mode override (None = auto: interpret off
-        TPU).
+      interpret: legacy backend override (True = the Pallas interpreter,
+        False = the compiled kernel, None = the platform default backend).
       backend: kernel backend override ('pallas' | 'compiled' |
         'interpret', DESIGN.md §13); wins over ``interpret``.
       block_packets: packets per fused-kernel grid step.

@@ -247,3 +247,60 @@ def test_env_var_selects_execution_path(monkeypatch):
     monkeypatch.setenv(BACKEND_ENV_VAR, "compiled")
     assert pallas_launch_count(bt_count, s) == 1
     assert pallas_launch_count(lambda v: bt_count(v, backend="compiled"), s) == 0
+
+
+def test_quantizer_pads_rows_to_whole_steps():
+    """Block rows that are not a multiple of the grid step are zero-padded
+    (not stepped one row at a time) and trimmed: bit-exact with compiled."""
+    from repro.kernels.quantize import (
+        quantize_egress_compiled,
+        quantize_egress_pallas,
+    )
+
+    rng = np.random.default_rng(23)
+    x = jnp.asarray(rng.normal(size=(77 * 64,)).astype(np.float32))
+    got = quantize_egress_pallas(x, block=64, rows_per_step=32, interpret=True)
+    # jitted, as the entry point runs it (XLA may divide by a constant as
+    # a reciprocal product, so eager and jitted scales differ in an ulp)
+    ref = jax.jit(quantize_egress_compiled, static_argnames="block")(
+        x, block=64
+    )
+    assert got[0].shape == (77 * 64,) and got[1].shape == (77,)
+    for a, b in zip(got, ref):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    with pytest.raises(ValueError, match="multiple of 32"):
+        quantize_egress_pallas(x, block=64, rows_per_step=20)
+
+
+@pytest.mark.skipif(jax.default_backend() == "tpu", reason="needs no TPU")
+def test_pallas_kernels_never_fall_back_to_the_interpreter():
+    """Off-TPU a ``*_pallas`` call without ``interpret=True`` is refused;
+    it does not quietly run the interpreter in place of the chip."""
+    from repro.kernels.btcount import bt_count_pallas
+
+    s = jnp.zeros((40, 8), jnp.uint8)
+    with pytest.raises(Exception):
+        jax.block_until_ready(bt_count_pallas(s))
+    assert int(bt_count_pallas(s, interpret=True)) == 0
+
+
+def test_enable_compilation_cache_paths(monkeypatch):
+    """CLI runs cache compiles in $JAX_COMPILATION_CACHE_DIR when set, else
+    at the fixed ``<repo>/.jax_cache``; importing repro sets neither."""
+    import pathlib
+    import sys
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(repo))
+    from benchmarks.run import enable_compilation_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert enable_compilation_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == "/elsewhere/cache"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compilation_cache() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(repo / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -76,9 +76,8 @@ def test_explicit_compressed_dp_matches_psum():
         def f(g, e):
             out, ne = compressed_psum(g[0], e[0], cfg, ("data",))
             return out[None], ne[None]
-        from repro.compat import shard_map
         with mesh:
-            out, _ = jax.jit(shard_map(
+            out, _ = jax.jit(jax.shard_map(
                 f, mesh=mesh, in_specs=(P("data"), P("data")),
                 out_specs=(P("data"), P("data"))))(g, err)
         want = np.asarray(g).sum(0)

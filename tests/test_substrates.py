@@ -92,9 +92,7 @@ def _run_compressed(mode, g, err, perm=None, inv=None):
 
     @jax.jit
     def f(g, err):
-        from repro.compat import shard_map
-
-        return shard_map(
+        return jax.shard_map(
             lambda g, e: compressed_psum(g, e, cfg, ("data",), perm, inv),
             mesh=mesh,
             in_specs=(jax.sharding.PartitionSpec(), jax.sharding.PartitionSpec()),
