@@ -3,11 +3,11 @@
 This module is the ONLY thing production code imports for telemetry.  It
 holds one mutable slot, ``SINK`` — ``None`` by default — that
 ``repro.obs`` installs a collector into while a ``collect()`` /
-``tracing()`` context is active.  With the slot empty every probe is a
-single attribute test against ``None`` executed in Python OUTSIDE any
-traced computation, so the traced jaxpr of every kernel entry point is
-byte-identical whether ``repro.obs`` is imported, active, or absent
-(asserted in ``tests/test_obs.py``).
+``tracing()`` / ``profiling()`` context is active.  With the slot empty
+every probe is a single attribute test against ``None`` executed in
+Python OUTSIDE any traced computation, so the traced jaxpr of every
+kernel entry point is byte-identical whether ``repro.obs`` is imported,
+active, or absent (asserted in ``tests/test_obs.py``).
 
 Deliberately dependency-free: importing this module never imports
 ``repro.obs`` (nor jax), so the hot path carries no observability code
@@ -45,12 +45,18 @@ _NULL_SPAN = _NullSpan()
 
 
 def active() -> bool:
-    """True while at least one collector (registry or tracer) is active."""
-    return SINK is not None
+    """True while at least one collector (registry or tracer) is active.
+
+    The profiler consumer (``repro.obs.profiling``) alone leaves this
+    False: it records spans only, so the per-link telemetry this guards
+    (events and the ``int()`` reads that fill them) stays off in a
+    profiled run, which then does the work an unprofiled one does."""
+    s = SINK
+    return s is not None and s.collecting
 
 
 def span(kind: str, **data):
-    """A context manager timing one probe span (no-op when inactive).
+    """A context manager around one probe span (no-op when inactive).
 
     ``kind`` names the probe point (e.g. ``"kernel.dispatch"``); ``data``
     carries JSON-safe scalars only — probe sites fire during jax tracing

@@ -303,9 +303,11 @@ def _measure_grid(
             )
         toggles = None
         if activity_windows is not None:
-            toggles = np.asarray(raw.toggles, dtype=np.int64)
+            with _obs.span("dse.readback", width=width):
+                toggles = np.asarray(raw.toggles, dtype=np.int64)
             raw = raw.bt
-        out = np.asarray(raw, dtype=np.int64)  # (L, C, 3)
+        with _obs.span("dse.readback", width=width):
+            out = np.asarray(raw, dtype=np.int64)  # (L, C, 3)
         if _obs.active():
             # per-link baseline BT of this width's launch (config 0 is
             # always the unsorted/uncoded baseline)

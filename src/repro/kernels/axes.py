@@ -744,6 +744,7 @@ def bt_axes_pallas(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="bt_axes_kernel",
     )(*args)
 
 

@@ -63,6 +63,7 @@ def bt_count_pallas(
         out_specs=pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(grid + (1, 1), jnp.int32),
         interpret=interpret,
+        name="bt_count_kernel",
     )(a, b)
     return partials.sum()
 

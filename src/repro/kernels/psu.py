@@ -150,6 +150,7 @@ def psu_sort_pallas(
         out_specs=[spec, spec],
         out_shape=out_shape,
         interpret=interpret,
+        name="psu_sort_kernel",
     )(packets.astype(jnp.int32))
 
 

@@ -79,6 +79,7 @@ def quantize_egress_pallas(
             jax.ShapeDtypeStruct((padded, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="quantize_kernel",
     )(xr)
     return q[:rows].reshape(m), s[:rows, 0]
 

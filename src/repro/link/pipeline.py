@@ -208,8 +208,9 @@ class TxPipeline:
             "link.tx", path="fused" if fused else "staged", key=s.key,
             codec=s.codec, packets=int(inputs.shape[0]),
         ):
-            xi = self.encode(inputs)
-            wi = self.encode(weights) if weights is not None else None
+            with _obs.span("link.stage", stage="encode"):
+                xi = self.encode(inputs)
+                wi = self.encode(weights) if weights is not None else None
             if fused:
                 res = psu_stream(
                     xi,
@@ -274,8 +275,10 @@ class TxPipeline:
         BT win is net of the codec's own overhead."""
         res = self.run(inputs, weights)
         num_flits, lanes = (int(d) for d in res.stream.shape)
-        bt_i, bt_w = int(res.bt_input), int(res.bt_weight)
-        aux, wires = int(res.bt_aux), self._extra_wires(lanes)
+        with _obs.span("link.readback"):
+            bt_i, bt_w = int(res.bt_input), int(res.bt_weight)
+            aux = int(res.bt_aux)
+        wires = self._extra_wires(lanes)
         energy = self.power.coded_link_energy_pj(
             bt_i + bt_w, aux, num_flits, 8 * lanes, wires
         )
@@ -342,10 +345,11 @@ class TxPipeline:
     def measure_rows(self, rows: jax.Array, name: str = "rows") -> LinkReport:
         """BT / energy report for streaming ``rows`` under this spec."""
         stream, bt_aux = self._row_wire(rows)
-        aux = int(bt_aux)
-        bt = int(
-            bt_count(stream, interpret=self._interpret, backend=self._backend)
+        bt = bt_count(
+            stream, interpret=self._interpret, backend=self._backend
         )
+        with _obs.span("link.readback"):
+            aux, bt = int(bt_aux), int(bt)
         num_flits, lanes = (int(d) for d in stream.shape)
         wires = self._extra_wires(lanes)
         energy = self.power.coded_link_energy_pj(

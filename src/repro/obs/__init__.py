@@ -3,7 +3,7 @@
 #   metrics.py  - counter/gauge/histogram registry + scoped collect()
 #   trace.py    - span API emitting Chrome/Perfetto trace-event JSON
 #   probes.py   - the sink behind repro._obs_hooks: probe vocabulary,
-#                 collect()/tracing() activation
+#                 collect()/tracing()/profiling() activation
 #   report.py   - per-link BT tables, top-N hottest links, CSV/JSON dumps
 #   activity.py - wire-level switching-activity profiles (DESIGN.md §15)
 #   saif.py     - SAIF / VCD export of measured activity for EDA flows
@@ -42,6 +42,7 @@ from .probes import (
     active_registries,
     active_tracers,
     collect,
+    profiling,
     tracing,
 )
 from .report import (
@@ -73,6 +74,7 @@ __all__ = [
     "PROBE_KINDS",
     "collect",
     "tracing",
+    "profiling",
     "active_registries",
     "active_tracers",
     "link_table",

@@ -1,10 +1,11 @@
-"""The probe layer: routes hook firings into registries and tracers
-(DESIGN.md §14).
+"""The probe layer: routes hook firings into registries, tracers and the
+JAX profiler (DESIGN.md §14).
 
 Production modules call ``repro._obs_hooks.span/event`` at fixed probe
-points; while at least one :func:`collect` or :func:`tracing` context is
-active this module's sink is installed into the hook slot and every firing
-fans out to all active collectors.  The probe vocabulary:
+points; while at least one :func:`collect`, :func:`tracing` or
+:func:`profiling` context is active this module's sink is installed into
+the hook slot and every firing fans out to all active consumers.  The
+probe vocabulary:
 
   =================  =====  ==============================================
   kind               form   fired by
@@ -13,8 +14,11 @@ fans out to all active collectors.  The probe vocabulary:
                             ``repro.kernels.ops`` (resolved backend,
                             shapes, grid blocks, pallas launches)
   link.tx            span   ``link.TxPipeline.run`` (fused or staged)
-  link.stage         span   each staged-path stage (order/assemble/codec/
-                            bt) inside ``TxPipeline.run``
+  link.stage         span   each stage inside ``TxPipeline.run``: encode
+                            (both paths), order/assemble/codec/bt (staged
+                            path)
+  link.readback      span   the host reads that end ``TxPipeline.measure``
+                            / ``measure_rows`` (each waits on the device)
   link.report        event  ``TxPipeline.measure``/``measure_rows`` —
                             per-stream BT/energy totals
   noc.expand         span   ``noc.expand_link_streams``
@@ -28,6 +32,8 @@ fans out to all active collectors.  The probe vocabulary:
                             toggle telemetry (DESIGN.md §15)
   dse.measure        span   each per-width multi-axis launch in
                             ``dse.evaluate_grid``
+  dse.readback       span   each host read of a launch's results in
+                            ``dse.evaluate_grid`` (BT table, toggles)
   dse.link           event  one per measurement link of a DSE grid launch
   dse.point          event  one per evaluated design point
   codec.stream       event  per-stream totals in ``codec.compare_streams``
@@ -38,17 +44,21 @@ fans out to all active collectors.  The probe vocabulary:
                             module run
   =================  =====  ==============================================
 
-Span firings become Chrome trace spans on every active tracer plus a
-``<kind>.calls`` counter and ``<kind>.seconds`` histogram (labeled by the
-kind's identity keys) on every active registry; event firings become
-instant trace events plus the per-kind counters below.  Unknown kinds
-still count (``<kind>.calls``) so new probe points degrade gracefully.
+Span firings become Chrome trace spans on every active tracer, a
+``<kind>.calls`` counter (labeled by the kind's identity keys) on every
+active registry, and, under :func:`profiling`, a
+``jax.profiler.TraceAnnotation`` named by the kind, on the profiler's clock
+beside the device's events.  Event firings become instant trace events
+plus the per-kind counters below; the profiler consumer ignores them.
+Unknown kinds still count (``<kind>.calls``) so new probe points degrade
+gracefully.
 """
 
 from __future__ import annotations
 
-import time
 from contextlib import contextmanager
+
+from jax.profiler import TraceAnnotation
 
 from repro import _obs_hooks
 
@@ -60,6 +70,7 @@ __all__ = [
     "PROBE_KINDS",
     "collect",
     "tracing",
+    "profiling",
     "active_registries",
     "active_tracers",
 ]
@@ -71,6 +82,7 @@ PROBE_KINDS: dict[str, str] = {
     "kernel.dispatch": "span",
     "link.tx": "span",
     "link.stage": "span",
+    "link.readback": "span",
     "link.report": "event",
     "link.activity": "event",
     "noc.expand": "span",
@@ -78,6 +90,7 @@ PROBE_KINDS: dict[str, str] = {
     "noc.link": "event",
     "noc.contend": "event",
     "dse.measure": "span",
+    "dse.readback": "span",
     "dse.link": "event",
     "dse.point": "event",
     "codec.stream": "event",
@@ -85,9 +98,9 @@ PROBE_KINDS: dict[str, str] = {
     "bench.module": "span",
 }
 
-# label keys lifted from span payloads into metric series identity —
-# everything else stays trace-only (unbounded-cardinality values like
-# shapes must never become label sets)
+# label keys lifted from span payloads into metric series identity and
+# profiler annotation arguments — everything else stays trace-only
+# (unbounded-cardinality values like shapes must never become label sets)
 _SPAN_LABELS: dict[str, tuple[str, ...]] = {
     "kernel.dispatch": ("entry", "backend"),
     "link.tx": ("path", "key", "codec"),
@@ -95,6 +108,7 @@ _SPAN_LABELS: dict[str, tuple[str, ...]] = {
     "noc.expand": ("topology", "sort_at"),
     "noc.simulate": ("topology", "sort_at"),
     "dse.measure": ("width",),
+    "dse.readback": ("width",),
     "bench.module": ("module",),
 }
 
@@ -104,10 +118,9 @@ def _labels(kind: str, data: dict) -> dict:
     return {k: data[k] for k in keys if k in data}
 
 
-def _record_span(reg: Registry, kind: str, data: dict, seconds: float) -> None:
+def _record_span(reg: Registry, kind: str, data: dict) -> None:
     labels = _labels(kind, data)
     reg.counter(f"{kind}.calls", **labels).inc()
-    reg.histogram(f"{kind}.seconds", **labels).observe(seconds)
     if kind == "kernel.dispatch":
         reg.counter(
             "kernel.pallas_launches", **_labels(kind, data)
@@ -181,9 +194,10 @@ def _record_event(reg: Registry, kind: str, data: dict) -> None:
 
 
 class _SpanCtx:
-    """One probe span fanned out to every active tracer + registry."""
+    """One probe span fanned out to every active tracer, registry and, when
+    profiling, the profiler's trace."""
 
-    __slots__ = ("_sink", "_kind", "_data", "_ends", "_t0")
+    __slots__ = ("_sink", "_kind", "_data", "_ends", "_note")
 
     def __init__(self, sink: "_Sink", kind: str, data: dict) -> None:
         self._sink, self._kind, self._data = sink, kind, data
@@ -192,15 +206,21 @@ class _SpanCtx:
         self._ends = [
             t.begin(self._kind, args=self._data) for t in self._sink.tracers
         ]
-        self._t0 = time.perf_counter()
+        self._note = None
+        if self._sink.profiling:
+            self._note = TraceAnnotation(
+                self._kind, **_labels(self._kind, self._data)
+            )
+            self._note.__enter__()
         return self
 
     def __exit__(self, *exc):
-        seconds = time.perf_counter() - self._t0
+        if self._note is not None:
+            self._note.__exit__(*exc)
         for end in self._ends:
             end()
         for reg in self._sink.registries:
-            _record_span(reg, self._kind, self._data, seconds)
+            _record_span(reg, self._kind, self._data)
         return False
 
 
@@ -210,6 +230,12 @@ class _Sink:
     def __init__(self) -> None:
         self.registries: list[Registry] = []
         self.tracers: list[Tracer] = []
+        self.profiling = 0  # depth of open profiling() scopes
+
+    @property
+    def collecting(self) -> bool:
+        """A registry or tracer is active (``_obs_hooks.active()``)."""
+        return bool(self.registries or self.tracers)
 
     def span(self, kind: str, data: dict) -> _SpanCtx:
         return _SpanCtx(self, kind, data)
@@ -226,7 +252,7 @@ _SINK = _Sink()
 
 def _refresh() -> None:
     _obs_hooks.SINK = (
-        _SINK if (_SINK.registries or _SINK.tracers) else None
+        _SINK if (_SINK.collecting or _SINK.profiling) else None
     )
 
 
@@ -266,4 +292,25 @@ def tracing(tracer: Tracer | None = None):
         yield tr
     finally:
         _SINK.tracers.remove(tr)
+        _refresh()
+
+
+@contextmanager
+def profiling():
+    """Write every probe span into the JAX profiler's trace for the
+    with-body.
+
+    Each span enters a ``jax.profiler.TraceAnnotation`` named by its kind,
+    with the kind's identity labels as arguments, so a profiler session
+    (``jax.profiler.start_trace``/``trace``) holds the program's host spans
+    on the device events' clock.  Events are not recorded, and
+    ``_obs_hooks.active()`` stays False under this consumer alone.
+    Re-entrant; leaving the last scope (also by an exception) empties the
+    hook slot again when nothing else collects."""
+    _SINK.profiling += 1
+    _refresh()
+    try:
+        yield
+    finally:
+        _SINK.profiling -= 1
         _refresh()

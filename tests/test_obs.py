@@ -141,6 +141,56 @@ def test_hooks_inactive_by_default():
     _obs_hooks.event("noc.link", link=0)  # swallowed
 
 
+# ------------------------------------------------ the profiler consumer
+
+
+def test_jaxpr_identical_under_profiling(tmp_path):
+    """Spans entered as profiler annotations, in a live profiler session,
+    leave every traced jaxpr byte-identical."""
+    before = _jaxprs()
+    with jax.profiler.trace(str(tmp_path)), obs.profiling():
+        during = _jaxprs()
+    assert before == during == _jaxprs()
+
+
+def test_profiling_alone_is_not_active():
+    """The profiler consumer installs the sink but keeps ``active()``
+    False, so the telemetry it guards stays off; spans still count on a
+    registry collecting beside it, and no span is timed on the host."""
+    x = _packets()
+    with obs.profiling():
+        assert _obs_hooks.SINK is not None
+        assert not _obs_hooks.active()
+        _obs_hooks.event("noc.link", link=0)  # no collector: swallowed
+        with obs.collect() as reg:
+            assert _obs_hooks.active()
+            TxPipeline(_input_spec(), interpret=True).measure(x)
+        assert not _obs_hooks.active()
+    assert _obs_hooks.SINK is None
+    assert reg.value("link.readback.calls") == 1
+    assert reg.value("link.stage.calls", stage="encode") == 1
+    assert reg.to_dict()["histograms"] == []
+
+
+def test_profiling_nests_and_restores_the_slot():
+    with obs.profiling():
+        with obs.profiling():
+            assert _obs_hooks.SINK is not None
+        assert _obs_hooks.SINK is not None
+    assert _obs_hooks.SINK is None
+    with pytest.raises(RuntimeError, match="inside a span"):
+        with obs.profiling(), obs.profiling():
+            with _obs_hooks.span("link.tx", path="fused"):
+                raise RuntimeError("inside a span")
+    assert _obs_hooks.SINK is None
+    with obs.collect():
+        with pytest.raises(RuntimeError):
+            with obs.profiling():
+                raise RuntimeError
+        assert _obs_hooks.SINK is not None and _obs_hooks.active()
+    assert _obs_hooks.SINK is None
+
+
 # --------------------------------------------------- probe vocabulary
 
 

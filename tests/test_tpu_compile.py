@@ -15,6 +15,7 @@ compiled without a chip cannot be read back here.
 """
 
 import pathlib
+import re
 import sys
 
 import jax
@@ -156,6 +157,34 @@ def test_quantize_compile(one_chip, size):
         lambda x: quantize_egress(x, backend="pallas"),
         ((size,), jnp.float32),
     )
+
+
+@pytest.mark.parametrize(
+    "kernel, fn, shape",
+    [
+        ("bt_count_kernel", lambda s: bt_count(s, backend="pallas"),
+         (513, 8)),
+        ("quantize_kernel", lambda x: quantize_egress(x, backend="pallas"),
+         (1 << 16,)),
+        ("psu_sort_kernel", lambda x: psu_sort(x, k=4, backend="pallas"),
+         LENET_SEPARATE),
+        ("bt_axes_kernel", lambda x: psu_stream(
+            x, input_lanes=chip_smoke.LANES, backend="pallas"),
+         LENET_SEPARATE),
+    ],
+    ids=["bt_count", "quantize", "psu_sort", "bt_axes"],
+)
+def test_kernel_names(one_chip, kernel, fn, shape):
+    """Each Pallas kernel compiles to a custom call named by its ``name=``,
+    the operation's name in a device trace, whatever its entry is called."""
+    dtype = jnp.float32 if kernel == "quantize_kernel" else jnp.uint8
+    text = _compile(one_chip, fn, (shape, dtype)).as_text()
+    names = re.findall(
+        r"%([\w.-]+) = .*custom_call_target=\"tpu_custom_call\"", text
+    )
+    assert names, text
+    for name in names:
+        assert re.fullmatch(rf"{kernel}(\.\d+)?", name), name
 
 
 def test_sharded_links_compile_four_chips(topo):
