@@ -13,8 +13,11 @@ four with one kernel whose launch carries three orthogonal axes:
     count and everything past it is masked *inside* the kernel.
   * **variant** (ordering) — static, unrolled at trace time: 'none' /
     'column_major' / 'acc' / 'app'(k) x direction.  One popcount pass per
-    block is shared by every bucketing; one permutation-matrix reorder is
-    shared by every config naming the same ordering.
+    block is shared by every bucketing; each sorted ordering takes its rank
+    from the PSU's comparison-free counting sort (``psu._rank_from_keys``:
+    bucket one-hots, one triangular prefix product, histogram starts), and
+    one permutation-matrix reorder is shared by every config naming the
+    same ordering.
   * **codec** — static, unrolled at trace time: 'none' / 'gray' /
     'sign_magnitude' / 'transition' / 'bus_invert'(partition), applied to
     the assembled wire per config (DESIGN.md §11/§12).
@@ -57,7 +60,7 @@ from repro.core.coding import (
     sign_magnitude_encode_bytes,
 )
 
-from .psu import _popcount_bits, _rank_from_keys
+from .psu import _onehot_dot, _popcount_bits, _rank_from_keys
 
 __all__ = [
     "Variant",
@@ -215,21 +218,6 @@ def _bus_invert_bits(hd: jax.Array, lbits: int) -> tuple[jax.Array, jax.Array]:
     v0 = jnp.concatenate([zeros, x], axis=0)
     # no tie yet -> the entry bit still propagates: v1 = v0 ^ [no tie <= t]
     return v0, v0 ^ jnp.concatenate([zeros + 1, 1 - r], axis=0)
-
-
-def _onehot_dot(a: jax.Array, sel: jax.Array, dims) -> jax.Array:
-    """Exact integer contraction of ``a`` with a 0/1 selector on the MXU.
-
-    Every operand here is a small integer (bytes, counts, indices), so an
-    f32 product at HIGHEST precision is exact; the result is int32.
-    """
-    return lax.dot_general(
-        a.astype(jnp.float32),
-        sel.astype(jnp.float32),
-        dimension_numbers=(dims, ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=lax.Precision.HIGHEST,
-    ).astype(jnp.int32)
 
 
 def _group_matrix(lanes: int, pw: int, per_lane: int = 1) -> jax.Array:
@@ -409,7 +397,7 @@ def _axes_block(
                 key, nb = (pc * k) // (width + 1), k
             if descending:
                 key = (nb - 1) - key
-            rank = _rank_from_keys(key)
+            rank = _rank_from_keys(key, nb)
             # --- reorder: one permutation-matrix MXU product yields the
             # ordered payloads (and, in emit_stream mode, `order` = the
             # permuted iota) in a single contraction (DESIGN.md §3.2) ---

@@ -8,16 +8,22 @@ hardware dataflow of Fig. 1 stage-for-stage on TPU vector units
                       4-bit LUT + adder tree,
   bucket encoder   -> integer multiply/divide (APP only; compiled away for
                       ACC exactly as the paper's synthesis prunes the LUT),
-  histogram + prefix sum + index mapping
-                   -> rank = #{lower-bucket elements} + #{earlier equal
-                      elements}, one (BP, N, N) compare + lane reduction
-                      (the hardware's prefix-sum addresses, with no scan),
-                      then the scatter SRAM write becomes a one-hot compare
-                      + weighted sum (no random-access writes).
+  one-hot + histogram + prefix sum + index mapping
+                   -> one (BP, N) 0/1 one-hot per bucket, the earlier-equal
+                      counts of every bucket as ONE exact 0/1 product with
+                      the strictly-triangular (N, N) matrix on the MXU,
+                      histograms and start addresses unrolled over the nb
+                      buckets, rank = start[key] + #earlier-equal — the
+                      hardware's addresses in O(N * nb) vector work per
+                      packet, with no comparison between elements and no
+                      in-kernel scan,
+  scatter SRAM write
+                   -> a one-hot compare + weighted sum (no random-access
+                      writes).
 
-Block shapes: packets are (BP, N) int32 in VMEM; the (BP, N, N)
-intermediates bound VMEM use, so BP defaults to 64 packets (N=64: 1 MiB
-per int32 temporary, well inside a v5e core's VMEM).
+Block shapes: packets are (BP, N) int32 in VMEM; the scatter's (BP, N, N)
+one-hot bounds VMEM use, so BP defaults to 64 packets (N=64: 1 MiB per
+int32 temporary, well inside a v5e core's VMEM).
 """
 
 from __future__ import annotations
@@ -48,24 +54,55 @@ def _popcount_bits(x: jax.Array, width: int) -> jax.Array:
     return v & jnp.int32(0x1F)
 
 
-def _rank_from_keys(key: jax.Array) -> jax.Array:
-    """Stages 2-3 of the PSU on one (BP, N) int32 key block: the stable
-    counting-sort output address of every element.
+def _onehot_dot(a: jax.Array, sel: jax.Array, dims) -> jax.Array:
+    """Exact integer contraction of ``a`` with a 0/1 selector on the MXU.
 
-    rank_i = #{j : key_j < key_i} + #{j < i : key_j == key_i} — the
-    histogram prefix sum (elements in lower buckets) plus the earlier-equal
-    count, evaluated as one (BP, N, N) comparison and a lane reduction, so
-    the kernel needs no in-kernel scan.  Factored out of
-    :func:`_rank_block` so the multi-axis BT kernel (``axes.py``) can
-    derive several bucketings from ONE popcount pass.
+    Every operand here is a small integer (bytes, counts, indices), so an
+    f32 product at HIGHEST precision is exact; the result is int32.
+    """
+    return lax.dot_general(
+        a.astype(jnp.float32),
+        sel.astype(jnp.float32),
+        dimension_numbers=(dims, ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=lax.Precision.HIGHEST,
+    ).astype(jnp.int32)
+
+
+def _rank_from_keys(key: jax.Array, nb: int) -> jax.Array:
+    """Stages 2-3 of the PSU on one (BP, N) int32 key block with values in
+    ``[0, nb)``: the stable counting-sort output address of every element.
+
+    The hardware's dataflow, element by element: one-hot the key into the
+    ``nb`` buckets, count each bucket's earlier elements (one exact 0/1
+    product of the stacked one-hots with the strictly-triangular
+    ``[j < i]`` matrix — Mosaic has no ``cumsum``), take each bucket's
+    histogram from its last column and the start addresses as their
+    exclusive prefix sum (unrolled over the static ``nb``), and map
+    element i to ``start[key_i] + #{j < i : key_j == key_i}``.  No two
+    elements are compared.  Factored out of :func:`_rank_block` so the
+    multi-axis BT kernel (``axes.py``) can derive several bucketings from
+    ONE popcount pass.
     """
     bp, n = key.shape
-    ki = key[:, :, None]  # element i
-    kj = key[:, None, :]  # every other element j
-    i = lax.broadcasted_iota(jnp.int32, (bp, n, n), 1)
-    j = lax.broadcasted_iota(jnp.int32, (bp, n, n), 2)
-    before = (kj < ki) | ((kj == ki) & (j < i))
-    return before.astype(jnp.int32).sum(axis=2)
+    earlier = lax.broadcasted_iota(jnp.int32, (n, n), 0) < (
+        lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    )
+    hits = [key == b for b in range(nb)]
+    within = _onehot_dot(
+        jnp.concatenate([h.astype(jnp.float32) for h in hits], axis=0),
+        earlier,
+        ((1,), (0,)),
+    )  # (nb*BP, N): earlier elements in the same bucket
+    last = key[:, n - 1:]
+    rank = jnp.zeros_like(key)
+    start = jnp.zeros((bp, 1), jnp.int32)
+    for b, hit in enumerate(hits):
+        within_b = within[b * bp:(b + 1) * bp]
+        rank = jnp.where(hit, start + within_b, rank)
+        # bucket b's histogram: its count before the last slot, plus that
+        start = start + within_b[:, n - 1:] + (last == b).astype(jnp.int32)
+    return rank
 
 
 def _rank_block(
@@ -88,7 +125,7 @@ def _rank_block(
         key, nb = (p * k) // (width + 1), k
     if descending:
         key = (nb - 1) - key
-    return _rank_from_keys(key)
+    return _rank_from_keys(key, nb)
 
 
 def _psu_kernel(
@@ -165,8 +202,8 @@ def psu_sort_compiled(
     """The compiled (pure-jnp) backend of the PSU sort.
 
     Runs the SAME rank derivation as the kernel (:func:`_rank_block`) one
-    (``block_packets``, N) block at a time (``lax.map``, so the (BP, N, N)
-    compare never materializes for more than one block) and inverts the
+    (``block_packets``, N) block at a time (``lax.map``, so the stacked
+    one-hots never materialize for more than one block) and inverts the
     rank permutation with an argsort instead of the kernel's one-hot
     scatter (identical output on a permutation).  Bit-exact with the
     kernel.  P must be a multiple of ``block_packets``.
