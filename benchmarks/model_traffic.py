@@ -13,8 +13,9 @@ traffic — four real scenarios:
     multicast decode weight stream plus per-token KV bytes.
   * **train_allreduce**  — one eager train step: the gradient tree, i.e.
     the ring all-reduce payload.
-  * **moe_dispatch**     — one eager MoE block: the dispatched expert
-    capacity buffers (the ICI all-to-all leg).
+  * **moe_dispatch**     — the MoE model's forward under capture: each
+    expert layer's input rows and routing, dispatched as the
+    expert-parallel all-to-all (one flow per source chip and expert).
 
 Each scenario's captured workload runs through ``dse.evaluate_grid``
 (baseline / ACC / APP k=4 / APP+bus-invert composed, wire-resolved) and
@@ -193,14 +194,16 @@ def run(
         io_spec,
     )
 
-    moe_stream = sessions["moe_dispatch"].get("moe_dispatch", "expert_in")[0]
-    expert_in = jnp.asarray(
-        moe_stream.data.view(np.int8).reshape(moe_stream.source_shape)
-    )
+    # the first expert layer's all-to-all over 8 chips of the mesh (the
+    # smoke model's 8 experts, one a chip)
+    route = sessions["moe_dispatch"].routes[0]
+    moe_cfg = smoke_config(MOE_ARCH)
     noc_results["moe_dispatch"] = _noc_run(
         m44,
         moe_dispatch_flows(
-            expert_in, m44, 0, tuple(range(1, 16)), io_spec
+            route.x, route.expert_ids, m44,
+            num_experts=moe_cfg.moe.num_experts, chips=tuple(range(8)),
+            spec=io_spec,
         ),
         io_spec,
     )

@@ -1,4 +1,5 @@
-"""Architecture registry: the 10 assigned configs + reduced smoke variants.
+"""Architecture registry: the 10 assigned configs, deepseek-v2-lite, and
+reduced smoke variants.
 
 ``get_config(name)`` returns the exact assigned configuration;
 ``smoke_config(name)`` returns the same *family* at toy scale (few layers,
@@ -26,6 +27,7 @@ _MODULES = {
     "granite-moe-3b-a800m": "granite_moe_3b_a800m",
     "internvl2-26b": "internvl2_26b",
     "zamba2-1.2b": "zamba2_1_2b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCH_NAMES = list(_MODULES)

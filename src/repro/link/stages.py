@@ -311,14 +311,35 @@ def order_packets(
       weights: optional (P, N) uint8 paired weights (move with the inputs).
 
     Returns:
-      (ordered_inputs, ordered_weights_or_None).
+      (ordered_inputs, ordered_weights_or_None): each packet's elements in
+      the order :func:`make_order` gives.  A data-dependent order is one
+      stable sort of (key, position) carrying the bytes along, with no
+      (..., N, N) permutation matrix and no per-element gather, so whole
+      fabrics of packets reorder in one pass.
     """
-    order = make_order(strategy, inputs, **kwargs)
-    out_i = jnp.take_along_axis(inputs, order, axis=-1)
-    out_w = (
-        jnp.take_along_axis(weights, order, axis=-1) if weights is not None else None
-    )
-    return out_i, out_w
+    stage = KEY_STAGES.get(strategy)
+    if strategy == "none":
+        return inputs, weights
+    if stage is None or stage.data_independent:
+        order = make_order(strategy, inputs, **kwargs)
+        out_i = jnp.take_along_axis(inputs, order, axis=-1)
+        out_w = (
+            jnp.take_along_axis(weights, order, axis=-1)
+            if weights is not None else None
+        )
+        return out_i, out_w
+    keys, nb = stage.fn(inputs, lanes=kwargs.get("lanes", 8),
+                        width=kwargs.get("width", 8), k=kwargs.get("k", 4))
+    if kwargs.get("descending", False):
+        keys = (nb - 1) - keys
+    # one unique key per element, key-major then position: the stable order
+    n = inputs.shape[-1]
+    kt = jnp.uint16 if nb * n <= 1 << 16 else jnp.int32
+    pos = jax.lax.broadcasted_iota(kt, inputs.shape, inputs.ndim - 1)
+    riders = (inputs,) if weights is None else (inputs, weights)
+    out = jax.lax.sort((keys.astype(kt) * n + pos, *riders),
+                       dimension=inputs.ndim - 1, num_keys=1)
+    return out[1], (out[2] if weights is not None else None)
 
 
 def _legacy_strategy(name: str) -> Callable[..., jax.Array]:

@@ -1,4 +1,4 @@
-from .config import ModelConfig, MoEConfig, SSMConfig
+from .config import MLAConfig, ModelConfig, MoEConfig, SSMConfig
 from .transformer import (
     decode_step,
     encdec_forward,
@@ -12,6 +12,7 @@ from .transformer import (
 )
 
 __all__ = [
+    "MLAConfig",
     "ModelConfig",
     "MoEConfig",
     "SSMConfig",

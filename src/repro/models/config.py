@@ -26,10 +26,41 @@ class MoEConfig:
     router_aux_weight: float = 0.01
     # experts are sharded over the "model" axis; pad to a multiple of it
     pad_experts_to: int | None = None
+    # renormalize the top-k router probabilities to sum to 1 (False keeps
+    # the raw softmax probabilities, DeepSeek-V2's ``norm_topk_prob``)
+    norm_topk_prob: bool = True
+    # dropless dispatch in every forward (the decode step is always
+    # dropless): every (token, choice) pair reaches its expert as one
+    # grouped product over the pairs sorted by expert (``lax.ragged_dot``),
+    # with no (G, E, C, D) capacity buffer
+    dropless: bool = False
 
     @property
     def padded_experts(self) -> int:
         return self.pad_experts_to or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1)
+    without a query down-projection, with yarn RoPE on the decoupled
+    ``qk_rope_head_dim`` part."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    # yarn context extension of the rope part (factor 1: plain RoPE)
+    rope_factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,6 +96,11 @@ class ModelConfig:
     )
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    mla: Optional[MLAConfig] = None  # latent attention in place of GQA
+    # moe: the leading layers that keep a dense MLP of width d_ff
+    first_k_dense: int = 0
+    # multiply token embeddings by sqrt(d_model) (Gemma-style)
+    scale_embeddings: bool = True
     shared_attn_every: int = 6  # hybrid only
     # encoder-decoder (whisper): encoder layer count; frontend supplies
     # precomputed frame embeddings (conv stem is a stub per the assignment)
@@ -117,6 +153,13 @@ class ModelConfig:
             raise ValueError(f"{self.name}: family={self.family} requires ssm config")
         if self.family == "encdec" and self.n_enc_layers <= 0:
             raise ValueError(f"{self.name}: encdec requires n_enc_layers")
+        if self.first_k_dense and (
+            self.family != "moe" or self.first_k_dense >= self.n_layers
+        ):
+            raise ValueError(
+                f"{self.name}: first_k_dense={self.first_k_dense} needs a "
+                f"moe family with more than that many layers"
+            )
         _ = self.q_rep
         return self
 
@@ -126,6 +169,14 @@ class ModelConfig:
         hd = self.resolved_head_dim
         total = v * d * (1 if self.tie_embeddings else 2)
         attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+        if self.mla is not None:
+            a, h = self.mla, self.n_heads
+            attn = (
+                d * h * a.qk_head_dim
+                + d * (a.kv_lora_rank + a.qk_rope_head_dim)
+                + a.kv_lora_rank * h * (a.qk_nope_head_dim + a.v_head_dim)
+                + h * a.v_head_dim * d
+            )
 
         def mlp(ff: int) -> int:
             gates = 2 if self.act in ("swiglu", "geglu") else 1
@@ -136,8 +187,11 @@ class ModelConfig:
         elif self.family == "moe":
             m = self.moe
             expert = d * m.d_ff_expert * 3  # gate/up/down
-            total += self.n_layers * (
-                attn + m.num_experts * expert + d * m.num_experts + 2 * d
+            k = self.first_k_dense
+            total += k * (attn + mlp(self.d_ff) + 2 * d)
+            total += (self.n_layers - k) * (
+                attn + (m.num_experts + m.num_shared_experts) * expert
+                + d * m.num_experts + 2 * d
             )
         elif self.family == "ssm":
             total += self.n_layers * self._ssm_block_params()
@@ -160,8 +214,9 @@ class ModelConfig:
         d = self.d_model
         expert = d * m.d_ff_expert * 3
         total = self.param_count()
-        total -= self.n_layers * m.num_experts * expert
-        total += self.n_layers * (m.top_k + m.num_shared_experts) * expert
+        total -= (self.n_layers - self.first_k_dense) * (
+            m.num_experts - m.top_k
+        ) * expert
         return total
 
     def _ssm_block_params(self) -> int:

@@ -104,6 +104,10 @@ def mlp(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 
 def init_attention(key: jax.Array, cfg: ModelConfig) -> Params:
+    if cfg.mla is not None:
+        from .mla import init_mla
+
+        return init_mla(key, cfg)
     pdt = jnp.dtype(cfg.param_dtype)
     d, hd = cfg.d_model, cfg.resolved_head_dim
     ks = jax.random.split(key, 4)
@@ -146,7 +150,7 @@ def _sdpa_dense(q, k, v, scale: float, causal: bool) -> jax.Array:
         scores = jnp.where(mask[None, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhrqk,bkhd->bqhrd", probs, v)
-    return out.reshape(b, sq, h, d)
+    return out.reshape(b, sq, h, v.shape[-1])
 
 
 def _attn_block(q, k, v, scale, mask_bias):
@@ -194,6 +198,7 @@ def _sdpa_chunked(
     """
     b, sq, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     rep = h // hkv
     cq = min(chunk, sq)
     ck = min(chunk, sk)
@@ -216,7 +221,7 @@ def _sdpa_chunked(
         for i in range(nq):
             m = jnp.full((b, hkv, rep, cq), -1e30, jnp.float32)
             l = jnp.zeros((b, hkv, rep, cq), jnp.float32)
-            acc = jnp.zeros((b, cq, hkv, rep, d), q.dtype)
+            acc = jnp.zeros((b, cq, hkv, rep, dv), q.dtype)
             hi = min(nk, ((i + 1) * cq + hist + ck - 1) // ck)
             for j in range(hi):
                 diag = (j + 1) * ck > i * cq + hist  # block touches the mask
@@ -230,7 +235,7 @@ def _sdpa_chunked(
         def q_step(_, i):
             m = jnp.full((b, hkv, rep, cq), -1e30, jnp.float32)
             l = jnp.zeros((b, hkv, rep, cq), jnp.float32)
-            acc = jnp.zeros((b, cq, hkv, rep, d), q.dtype)
+            acc = jnp.zeros((b, cq, hkv, rep, dv), q.dtype)
             qi = qg[:, i]
 
             def kv_step(carry, j):
@@ -244,8 +249,8 @@ def _sdpa_chunked(
             return None, _finalize(m, l, acc)
 
         _, out = lax.scan(q_step, None, jnp.arange(nq), unroll=unroll)
-        out = jnp.moveaxis(out, 0, 1)  # (B, nq, cq, hkv, rep, d)
-    return out.reshape(b, sq, h, d)
+        out = jnp.moveaxis(out, 0, 1)  # (B, nq, cq, hkv, rep, dv)
+    return out.reshape(b, sq, h, dv)
 
 
 def attention(
@@ -256,6 +261,10 @@ def attention(
     causal: bool = True,
 ) -> jax.Array:
     """Full-sequence attention (training / prefill).  x: (B, S, d)."""
+    if cfg.mla is not None:
+        from .mla import mla_attention
+
+        return mla_attention(params, x, cfg, positions, causal)
     q, k, v = _qkv(params, x, cfg, positions)
     scale = 1.0 / math.sqrt(cfg.resolved_head_dim)
     if not causal or cfg.attn_impl == "dense" or x.shape[1] <= cfg.attn_chunk:

@@ -7,7 +7,8 @@ the scan body when ``cfg.remat`` (activation recomputation).
 
 Families:
   dense / vlm      -- GQA attention + (Ge/Swi)GLU MLP stack
-  moe              -- attention + top-k MoE MLP
+  moe              -- attention (GQA or latent) + top-k MoE MLP, after
+                      ``first_k_dense`` leading dense layers
   ssm              -- Mamba-2 / SSD stack (attention-free)
   hybrid           -- SSD stack with one SHARED attention+MLP block applied
                       after every ``shared_attn_every`` SSM layers (zamba2)
@@ -18,12 +19,15 @@ Families:
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+
+from repro import _obs_hooks
 
 from .config import ModelConfig
 from .layers import (
@@ -88,7 +92,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     if fam in ("dense", "vlm"):
         p["layers"] = _init_stack(ks[2], cfg, "attn", cfg.n_layers)
     elif fam == "moe":
-        p["layers"] = _init_stack(ks[2], cfg, "moe", cfg.n_layers)
+        k = cfg.first_k_dense
+        if k:
+            p["dense_layers"] = _init_stack(ks[3], cfg, "attn", k)
+        p["layers"] = _init_stack(ks[2], cfg, "moe", cfg.n_layers - k)
     elif fam == "ssm":
         p["layers"] = _init_stack(ks[2], cfg, "ssm", cfg.n_layers)
     elif fam == "hybrid":
@@ -131,11 +138,13 @@ def _attn_layer(lp: Params, h: jax.Array, cfg: ModelConfig, positions, causal=Tr
     return h + _tag(m, "mlp_out")
 
 
-def _moe_layer(lp: Params, h: jax.Array, cfg: ModelConfig, positions):
+def _moe_layer(lp: Params, h: jax.Array, cfg: ModelConfig, positions,
+               return_route: bool = False):
     a = attention(lp["attn"], rms_norm(h, lp["attn_norm"], cfg.rms_eps), cfg, positions, True)
     h = h + _tag(a, "attn_out")
-    y, aux = moe_block(lp["moe"], rms_norm(h, lp["mlp_norm"], cfg.rms_eps), cfg)
-    return h + _tag(y, "mlp_out"), aux
+    out = moe_block(lp["moe"], rms_norm(h, lp["mlp_norm"], cfg.rms_eps), cfg,
+                    return_route=return_route)
+    return (h + _tag(out[0], "mlp_out"), *out[1:])
 
 
 def _ssm_layer(lp: Params, h: jax.Array, cfg: ModelConfig):
@@ -175,7 +184,7 @@ def _maybe_remat(fn, cfg: ModelConfig):
 
 def embed_tokens(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
     h = params["embed"][tokens].astype(jnp.dtype(cfg.dtype))
-    return h * math.sqrt(cfg.d_model)
+    return h * math.sqrt(cfg.d_model) if cfg.scale_embeddings else h
 
 
 def forward(
@@ -205,10 +214,10 @@ def forward(
             return _attn_layer(lp, carry, cfg, positions, causal), None
         h, _ = _scan(cfg, _maybe_remat(body, cfg), h, params["layers"])
     elif fam == "moe":
-        def body(carry, lp):
-            return _moe_layer(lp, carry, cfg, positions)
-        h, auxs = _scan(cfg, _maybe_remat(body, cfg), h, params["layers"])
-        aux = aux + auxs.sum()
+        if _obs_hooks.capturing() and not isinstance(h, jax.core.Tracer):
+            h, aux = _moe_forward_captured(params, cfg, h, positions)
+        else:
+            h, aux = _moe_forward(params, cfg, h, positions)
     elif fam == "ssm":
         def body(carry, lp):
             return _ssm_layer(lp, carry, cfg), None
@@ -218,6 +227,51 @@ def forward(
     else:
         raise ValueError(f"forward() does not handle family {fam}; use encdec_forward")
     return rms_norm(h, params["final_norm"], cfg.rms_eps), aux
+
+
+def _moe_forward(params: Params, cfg: ModelConfig, h, positions):
+    """The MoE stack: the leading dense layers, then the expert layers."""
+    if "dense_layers" in params:
+        def dense_body(carry, lp):
+            return _attn_layer(lp, carry, cfg, positions), None
+        h, _ = _scan(cfg, _maybe_remat(dense_body, cfg), h,
+                     params["dense_layers"])
+
+    def body(carry, lp):
+        return _moe_layer(lp, carry, cfg, positions)
+    h, auxs = _scan(cfg, _maybe_remat(body, cfg), h, params["layers"])
+    return h, auxs.sum()
+
+
+@partial(jax.jit, static_argnames=("cfg",))
+def _dense_layer_at(stack: Params, i, h, positions, *, cfg: ModelConfig):
+    lp = jax.tree.map(lambda x: x[i], stack)
+    return _attn_layer(lp, h, cfg, positions)
+
+
+@partial(jax.jit, static_argnames=("cfg",))
+def _moe_layer_at(stack: Params, i, h, positions, *, cfg: ModelConfig):
+    lp = jax.tree.map(lambda x: x[i], stack)
+    return _moe_layer(lp, h, cfg, positions, return_route=True)
+
+
+def _moe_forward_captured(params: Params, cfg: ModelConfig, h, positions):
+    """:func:`_moe_forward` for a traffic capture: the same layer bodies,
+    one jitted call per layer, so that each expert layer hands back its
+    routing and the ``moe.dispatch`` tap fires with real arrays (inside
+    the scan it would see tracers).  Taken only while a capture is active
+    and ``forward`` runs outside ``jit``; every traced jaxpr is that of
+    :func:`_moe_forward`."""
+    k = cfg.first_k_dense
+    for i in range(k):
+        h = _dense_layer_at(params["dense_layers"], i, h, positions, cfg=cfg)
+    aux = jnp.float32(0.0)
+    for i in range(cfg.n_layers - k):
+        h, a, route = _moe_layer_at(params["layers"], i, h, positions,
+                                    cfg=cfg)
+        aux = aux + a
+        _obs_hooks.tap("moe.dispatch", layer=k + i, **route)
+    return h, aux
 
 
 def _hybrid_forward(params: Params, cfg: ModelConfig, h, positions):
@@ -331,6 +385,11 @@ def _attn_with_kv(lp, h, cfg, positions):
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, enc_len: int = 0) -> Params:
     """Abstract-safe cache allocation for every family."""
+    if cfg.mla is not None or cfg.first_k_dense:
+        raise NotImplementedError(
+            f"{cfg.name}: no KV cache for latent attention or leading dense "
+            "layers yet; run the full-sequence forward"
+        )
     dt = jnp.dtype(cfg.dtype)
     hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     fam = cfg.family

@@ -1,6 +1,6 @@
 """Traffic adapters: real workload tensors -> NoC flows.
 
-Three workload families from the existing layers, mapped onto fabric
+Workload families from the existing layers, mapped onto fabric
 traffic patterns (DESIGN.md §9):
 
   * **Conv platform** (paper §IV-B, ``benchmarks/lenet_workload.py``): the
@@ -16,6 +16,9 @@ traffic patterns (DESIGN.md §9):
     sharded over the routers of a ring schedule, each shard hopping to the
     next router — one step of a ring reduce-scatter, the ICI collective
     pattern of DESIGN.md §5 on the modeled fabric.
+  * **Expert-parallel dispatch** (``repro.models.moe``): one MoE layer's
+    all-to-all — every chip sends its tokens' rows to the chips holding
+    their experts, one flow per (source chip, expert).
   * **Fleet decode** (DESIGN.md §17, the ``fleet_noc`` benchmark):
     multi-tenant decode weight broadcast — users x layers x shards
     multicast flows on a large grid, tenants pinned to rows, shards to
@@ -27,11 +30,14 @@ Adapters only build ``TrafficFlow``s; ordering/packing/measuring stay in
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro import _obs_hooks as _obs
 from repro.link import LinkSpec, tensor_flit_stream
 from repro.traffic.ordering import int8_view
 
@@ -267,50 +273,106 @@ def ring_allreduce_flows(
     return flows
 
 
-def moe_dispatch_flows(
-    expert_in: jax.Array,
-    topo: Topology,
-    src: int,
-    expert_routers: Sequence[int],
-    spec: LinkSpec = LinkSpec(),
-) -> list[TrafficFlow]:
-    """MoE dispatch: each expert's capacity buffer unicast to its router.
+@partial(jax.jit, static_argnames=("per_chip", "experts"))
+def _dispatch_rows(wire, expert_ids, *, per_chip: int, experts: int):
+    """The (token, choice) rows grouped by (source chip, expert), token
+    order inside each group, and each group's row count."""
+    t, k = expert_ids.shape
+    src = jnp.arange(t, dtype=jnp.int32) // per_chip
+    key = (src[:, None] * experts + expert_ids.astype(jnp.int32)).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.bincount(key, length=(t // per_chip) * experts)
+    return jnp.take(wire, order // k, axis=0), counts
 
-    ``expert_in`` is the (G, E, C, D) dispatched buffer of
-    ``repro.models.moe.moe_block`` (or its captured int8 wire image from
-    ``repro.obs.capture``); expert e's slice ``expert_in[:, e]`` flows from
-    the dispatch router ``src`` to ``expert_routers[e % len]`` — the ICI
-    all-to-all leg of DESIGN.md §5 on the modeled fabric.  Tokens inside a
-    capacity buffer are an unordered set, which is exactly the permutation
-    freedom the paper's sorting unit exploits (``sort_at`` in
-    ``simulate_noc``).
+
+def moe_dispatch_flows(
+    rows: jax.Array,
+    expert_ids: jax.Array,
+    topo: Topology,
+    *,
+    num_experts: int,
+    chips: Sequence[int] | None = None,
+    spec: LinkSpec = LinkSpec(input_lanes=16, weight_lanes=0),
+) -> list[TrafficFlow]:
+    """Expert-parallel dispatch: the all-to-all of one MoE layer.
+
+    ``rows`` is the layer's (T, d) token rows (their int8 wire image, or a
+    float tensor quantized by ``int8_view``), ``expert_ids`` the (T, k)
+    experts each token is routed to (``repro.obs.MoeRoute``).  ``chips``
+    are the routers of the expert-parallel group in chip order (default:
+    every router); the tokens are split evenly over them in order (each
+    chip's data-parallel share) and chip ``c`` holds experts
+    ``c*E/C ... (c+1)*E/C - 1``.
+
+    One unicast flow per (source chip, expert) carries that chip's tokens
+    routed to the expert, in token order, whole tokens of ``d / elems``
+    packets each; flows are injected source-major, expert-minor.  A flow
+    whose expert lives on its source chip never enters the fabric and is
+    left out (the ``moe.load`` event counts them).  Tokens inside a flow
+    are an unordered set, the permutation freedom the paper's sorting
+    unit exploits (``sort_at`` in ``simulate_noc``).
+
+    The payloads are host arrays: one gather on the device groups every
+    row, and one transfer brings them over for ``FlowBatch`` to stack.
     """
     if spec.weight_lanes:
         raise ValueError("dispatch traffic is one-sided; use weight_lanes=0")
-    if expert_in.ndim != 4:
+    if rows.ndim != 2 or expert_ids.ndim != 2 or (
+            rows.shape[0] != expert_ids.shape[0]):
         raise ValueError(
-            f"expert_in must be (groups, experts, capacity, d_model), "
-            f"got shape {tuple(expert_in.shape)}"
+            f"need (tokens, d_model) rows and (tokens, top_k) expert ids, "
+            f"got {tuple(rows.shape)} and {tuple(expert_ids.shape)}"
         )
-    if not expert_routers:
-        raise ValueError("moe dispatch needs >= 1 expert router")
-    topo.coords(src)  # validates the router id
-    flows = []
-    for e in range(expert_in.shape[1]):
-        data = _wire_bytes(expert_in[:, e])
-        if int(data.size) < spec.elems_per_packet:
-            continue  # padded expert with an empty (sub-packet) buffer
-        flows.append(
-            TrafficFlow(
-                name=f"moe/expert{e}",
-                src=src,
-                dsts=(expert_routers[e % len(expert_routers)],),
-                inputs=packetize(data, spec.elems_per_packet),
+    t, k = (int(n) for n in expert_ids.shape)
+    elems = spec.elems_per_packet
+    width = int(rows.shape[1])
+    if width % elems:
+        raise ValueError(
+            f"a {width}-byte token row is not whole {elems}-byte packets"
+        )
+    routers = tuple(range(topo.num_routers)) if chips is None else tuple(chips)
+    for r in routers:
+        topo.coords(r)  # validates the router id
+    c = len(routers)
+    if not c or t % c or num_experts % c:
+        raise ValueError(
+            f"{t} tokens and {num_experts} experts must split evenly over "
+            f"{c} chips"
+        )
+    per_chip, held = t // c, num_experts // c
+    with _obs.span("moe.route", chips=c, experts=num_experts, tokens=t,
+                   top_k=k):
+        wire = _wire_bytes(rows).reshape(t, width)
+        grouped, counts = _dispatch_rows(
+            wire, jnp.asarray(expert_ids), per_chip=per_chip,
+            experts=num_experts)
+        grouped = np.asarray(grouped)
+        counts = np.asarray(counts).reshape(c, num_experts)
+    flows, start, local = [], 0, 0
+    for src in range(c):
+        for e in range(num_experts):
+            n = int(counts[src, e])
+            seg = grouped[start:start + n]
+            start += n
+            if not n:
+                continue
+            if e // held == src:
+                local += 1
+                continue
+            flows.append(
+                TrafficFlow(
+                    name=f"moe/chip{src}/expert{e}",
+                    src=routers[src],
+                    dsts=(routers[e // held],),
+                    inputs=seg.reshape(n * width // elems, elems),
+                )
             )
-        )
-    if not flows:
-        raise ValueError(
-            f"no expert buffer reaches one {spec.elems_per_packet}-byte "
-            "packet; capture more tokens or shrink the packet"
-        )
+    per_expert = counts.sum(axis=0)
+    _obs.event(
+        "moe.load", chips=c, experts=num_experts,
+        tokens_per_expert_max=int(per_expert.max()),
+        tokens_per_expert_mean=float(per_expert.mean()),
+        flows=len(flows), local_flows=local,
+        fabric_bytes=sum(int(f.inputs.size) for f in flows),
+    )
     return flows

@@ -7,12 +7,13 @@ traced host round-trips before the single batched BT launch.  This module
 replaces that walk with three batched steps over the routing tables a
 :class:`~repro.noc.routing.FabricPlan` compiled once:
 
-  1. :class:`FlowBatch` — every flow's packets stacked into ONE
-     device-resident (F, P_max, elems) tensor (zero-padded, per-flow packet
-     counts kept statically);
-  2. :func:`expand_fabric` — encode + per-packet sort order computed for
-     ALL flows in one call each, flows gathered into distinct-queue rows by
-     one (Q, P_q) index table, the hop-sort packet permutation applied as a
+  1. :class:`FlowBatch` — every flow's packets stacked back to back into
+     ONE device-resident (P, elems) tensor (zero-padded to a power of two
+     packets, per-flow packet counts kept statically);
+  2. :func:`expand_fabric` — encode + per-packet send order applied to
+     ALL flows in one call each (one stable sort of (key, position) per
+     packet), flows gathered into distinct-queue rows by one (Q, P_q)
+     index table, the hop-sort packet permutation applied as a
      masked batched counting sort, per-queue flit assembly vmapped over the
      registered ``repro.link`` stages, and the wire codec vmapped across
      queues (bus-invert's scan included) — invert-line state stays on
@@ -28,6 +29,7 @@ every existing test fabric.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple, Optional, Sequence
 
 import jax
@@ -37,7 +39,7 @@ import numpy as np
 from repro import _obs_hooks as _obs
 from repro.codec.schemes import codec_by_name
 from repro.core.sorting import counting_sort_indices
-from repro.link import ENCODE_STAGES, LinkSpec, make_order
+from repro.link import ENCODE_STAGES, LinkSpec, order_packets
 from repro.link.framing import assemble_stream
 from repro.link.stages import row_bucket_keys
 
@@ -80,13 +82,19 @@ def validate_flow(flow, spec: LinkSpec) -> None:
             )
 
 
+def _pow2(n: int) -> int:
+    return 1 << max(int(n) - 1, 0).bit_length()
+
+
 class FlowBatch(NamedTuple):
     """Every flow's packet payloads as one device-resident batch.
 
-    ``inputs`` is (F, P_max, elems) uint8 (flows shorter than P_max are
-    zero-padded — padding never reaches a measured wire, the queue tables
-    index real packets only); ``weights`` rides along for paired framings.
-    ``counts`` keeps each flow's real packet count statically.
+    ``inputs`` is (P', elems) uint8: the flows' packets back to back in
+    flow order, the total rounded up to a power of two packets (the pad
+    packets are zeros and never reach a measured wire: the queue tables
+    index real packets only); ``weights`` rides along for paired
+    framings.  ``counts`` keeps each flow's packet count statically; flow
+    ``f`` starts at packet ``starts[f]``.
     """
 
     inputs: jax.Array
@@ -98,8 +106,8 @@ class FlowBatch(NamedTuple):
         return len(self.counts)
 
     @property
-    def max_packets(self) -> int:
-        return 0 if not self.counts else int(self.inputs.shape[1])
+    def starts(self) -> tuple[int, ...]:
+        return tuple(int(s) for s in np.cumsum((0,) + self.counts[:-1]))
 
     @classmethod
     def from_flows(cls, flows: Sequence, spec: LinkSpec) -> "FlowBatch":
@@ -109,30 +117,32 @@ class FlowBatch(NamedTuple):
         for flow in flows:
             validate_flow(flow, spec)
         counts = tuple(int(f.inputs.shape[0]) for f in flows)
-        if not flows:
-            e = spec.elems_per_packet
-            return cls(jnp.zeros((0, 1, e), jnp.uint8), None, ())
-        pmax = max(counts)
-        xs = np.zeros((len(flows), pmax, spec.elems_per_packet), np.uint8)
-        for i, f in enumerate(flows):
-            xs[i, : counts[i]] = np.asarray(f.inputs, np.uint8)
+        # the total padded to a power of two: traffic whose packet count
+        # varies (one MoE layer to the next) reuses the expansion's
+        # compiled programs
+        total = _pow2(max(sum(counts), 1))
+
+        def stack(parts, elems):
+            out = np.zeros((total, elems), np.uint8)
+            if parts:
+                out[: sum(counts)] = np.concatenate(
+                    [np.asarray(x, np.uint8) for x in parts])
+            return jnp.asarray(out)
+
+        xs = stack([f.inputs for f in flows], spec.elems_per_packet)
         ws = None
-        if spec.weight_lanes:
-            ws = np.zeros(
-                (len(flows), pmax, spec.weight_elems_per_packet), np.uint8
-            )
-            for i, f in enumerate(flows):
-                ws[i, : counts[i]] = np.asarray(f.weights, np.uint8)
-        return cls(
-            jnp.asarray(xs), None if ws is None else jnp.asarray(ws), counts
-        )
+        if spec.weight_lanes and flows:
+            ws = stack([f.weights for f in flows],
+                       spec.weight_elems_per_packet)
+        return cls(xs, ws, counts)
 
 
 class FabricStreams(NamedTuple):
     """The fabric's distinct-queue wire streams, ready for ONE BT launch.
 
-    ``streams`` is (Q, T_max, bytes_per_flit) uint8 — one row per distinct
-    link queue, padded past each queue's real flit count with copies of its
+    ``streams`` is (Q, T, bytes_per_flit) uint8 — one row per distinct
+    link queue (T covers the longest queue, rounded up to a power of two
+    packets), padded past each queue's real flit count with copies of its
     last flit (the same self-consistent padding the legacy stacker used;
     the kernel masks past ``lengths`` either way).  ``aux_bt`` / ``inverts``
     carry the wire codec's invert-line transition counts and raw line
@@ -157,20 +167,22 @@ class FabricStreams(NamedTuple):
 
 
 def _queue_gather_table(
-    plan: FabricPlan, counts: tuple[int, ...], pmax: int
+    plan: FabricPlan, batch: FlowBatch
 ) -> tuple[np.ndarray, tuple[int, ...]]:
-    """(Q, P_qmax) flat packet indices per distinct queue + real counts.
+    """(Q, P_q) batch packet indices per distinct queue + real counts.
 
-    Queue slot j of queue q maps to flat index flow*P_max + packet of the
-    j-th packet in injection order (the legacy concatenation order); pad
-    slots point at index 0 and are masked everywhere downstream.
+    Queue slot j of queue q maps to the batch index of the j-th packet in
+    injection order (the legacy concatenation order); pad
+    slots point at index 0 and are masked everywhere downstream.  P_q is
+    the longest queue rounded up to a power of two, so that fabrics whose
+    longest queue varies reuse the compiled expansion and measurement.
     """
-    starts = [f * pmax for f in range(len(counts))]
+    counts, starts = batch.counts, batch.starts
     qcounts = tuple(
         sum(counts[f] for f in q) for q in plan.queues
     )
     qmax = max(qcounts, default=0)
-    table = np.zeros((len(plan.queues), max(qmax, 1)), np.int64)
+    table = np.zeros((len(plan.queues), _pow2(max(qmax, 1))), np.int64)
     for qi, q in enumerate(plan.queues):
         parts = [
             np.arange(starts[f], starts[f] + counts[f], dtype=np.int64)
@@ -254,33 +266,42 @@ def _expand_fabric(
             plan, jnp.zeros((nq, 1, spec.bytes_per_flit), jnp.uint8),
             (0,) * nq,
         )
-    encode = ENCODE_STAGES[spec.encode]
-    xi = encode(batch.inputs).astype(jnp.uint8)  # (F, Pmax, E)
-    wi = (
-        encode(batch.weights).astype(jnp.uint8)
-        if batch.weights is not None
-        else None
+    table, qcounts = _queue_gather_table(plan, batch)
+    lengths = tuple(c * spec.flits_per_packet for c in qcounts)
+    streams, aux, inverts = _expand_device(
+        batch.inputs, batch.weights, jnp.asarray(table),
+        jnp.asarray(qcounts, jnp.int32), spec=spec, sort_at=sort_at,
     )
-    # ONE order derivation for every packet of every flow (per-packet
-    # counting sort — identical to the per-flow legacy call)
-    order = make_order(
+    return FabricStreams(plan, streams, lengths, aux, inverts)
+
+
+@partial(jax.jit, static_argnames=("spec", "sort_at"))
+def _expand_device(inputs, weights, gather, qcounts, *, spec: LinkSpec,
+                   sort_at: str):
+    """The expansion's device work as one program: (streams, aux BT or
+    None, invert-line states or None) of the (Q, Pq) queue gather table,
+    each queue's real packet count in ``qcounts``."""
+    nq = gather.shape[0]
+    encode = ENCODE_STAGES[spec.encode]
+    xi = encode(inputs).astype(jnp.uint8)  # (P', E)
+    wi = encode(weights).astype(jnp.uint8) if weights is not None else None
+    # every packet of every flow put in send order ONCE, at the source (the
+    # per-flow legacy order, as one stable sort); the input order moves the
+    # weight half along only for the symmetric framing, as in
+    # assemble_stream
+    sym = wi is not None and wi.shape[-1] == xi.shape[-1]
+    xi, ws = order_packets(
         spec.key,
         xi,
+        wi if sym else None,
         lanes=spec.input_lanes,
         width=spec.width,
         k=spec.k,
         descending=spec.descending,
     )
-    f, pmax, e = (int(d) for d in xi.shape)
-    table, qcounts = _queue_gather_table(plan, batch.counts, pmax)
-    gather = jnp.asarray(table)  # (Q, Pq)
-    qx = jnp.take(xi.reshape(f * pmax, e), gather, axis=0)
-    qo = jnp.take(order.reshape(f * pmax, e), gather, axis=0)
-    qw = (
-        None
-        if wi is None
-        else jnp.take(wi.reshape(f * pmax, wi.shape[-1]), gather, axis=0)
-    )
+    wi = ws if sym else wi
+    qx = jnp.take(xi, gather, axis=0)
+    qw = None if wi is None else jnp.take(wi, gather, axis=0)
     if sort_at == "hop":
         rows = qx if qw is None else jnp.concatenate([qx, qw], axis=-1)
         levels = spec.k if spec.key == "app" else spec.width + 1
@@ -289,19 +310,22 @@ def _expand_fabric(
             width=spec.width, descending=spec.descending,
         )
         qx = jnp.take_along_axis(qx, perm[..., None], axis=1)
-        qo = jnp.take_along_axis(qo, perm[..., None], axis=1)
         if qw is not None:
             qw = jnp.take_along_axis(qw, perm[..., None], axis=1)
-    # per-queue flit assembly, vmapped over the queue axis
+    # per-queue flit assembly of the sent packets, vmapped over the queues;
+    # kept apart from the gathers, whose fusion with the lane packing takes
+    # the TPU compiler over a minute at fabric sizes
+    qx, qw = jax.lax.optimization_barrier((qx, qw))
     if qw is None:
         streams = jax.vmap(
-            lambda x, o: assemble_stream(x, None, spec, o, spec.pack)
-        )(qx, qo)
+            lambda x: assemble_stream(x, None, spec, None, spec.pack)
+        )(qx)
     else:
         streams = jax.vmap(
-            lambda x, w, o: assemble_stream(x, w, spec, o, spec.pack)
-        )(qx, qw, qo)
-    lengths = tuple(c * spec.flits_per_packet for c in qcounts)
+            lambda x, w: assemble_stream(x, w, spec, None, spec.pack)
+        )(qx, qw)
+    real = qcounts * spec.flits_per_packet  # each queue's real flit rows
+    t = streams.shape[1]
     aux = inverts = None
     if spec.codec != "none":
         codec = codec_by_name(spec.codec)
@@ -309,20 +333,17 @@ def _expand_fabric(
         streams = coded.wire.astype(jnp.uint8)
         if coded.invert is not None:
             inverts = coded.invert
-            t = int(streams.shape[1])
-            real = (
-                jnp.arange(1, t)[None, :, None]
-                < jnp.asarray(lengths, jnp.int32)[:, None, None]
-            )
-            flips = (inverts[:, 1:] != inverts[:, :-1]) & real
+            inside = jnp.arange(1, t)[None, :, None] < real[:, None, None]
+            flips = (inverts[:, 1:] != inverts[:, :-1]) & inside
             aux = flips.sum(axis=(1, 2)).astype(jnp.int32)
         else:
             aux = jnp.zeros((nq,), jnp.int32)
     # pad rows become copies of each queue's last real flit — the same
     # self-consistent padding the legacy stacker emitted (codec state was
     # already computed on the real region only, which comes first)
-    t = int(streams.shape[1])
-    last = jnp.maximum(jnp.asarray(lengths, jnp.int32) - 1, 0)
-    idx = jnp.minimum(jnp.arange(t, dtype=jnp.int32)[None, :], last[:, None])
-    streams = jnp.take_along_axis(streams, idx[..., None], axis=1)
-    return FabricStreams(plan, streams, lengths, aux, inverts)
+    last = jnp.take_along_axis(
+        streams, jnp.maximum(real - 1, 0)[:, None, None], axis=1)
+    streams = jnp.where(
+        jnp.arange(t, dtype=jnp.int32)[None, :, None] < real[:, None, None],
+        streams, last)
+    return streams, aux, inverts

@@ -251,8 +251,8 @@ def fabric_to_link_streams(fs: FabricStreams) -> LinkStreams:
         lanes = int(fs.streams.shape[-1])
         return LinkStreams((), jnp.zeros((0, 1, lanes), jnp.uint8), ())
     lq = jnp.asarray(plan.link_queue, jnp.int32)
-    stacked = jnp.take(fs.streams, lq, axis=0)
     lengths = fs.link_lengths()
+    stacked = jnp.take(fs.streams, lq, axis=0)[:, : max(max(lengths), 1)]
     if fs.aux_bt is None:
         aux = (0,) * len(plan.link_ids)
         inverts: tuple = ()
@@ -460,8 +460,10 @@ def _simulate_noc(
     latency: NocLatencyModel | None,
     name: str,
 ) -> NocReport:
-    plan = compile_fabric(topo, [(f.src, f.dsts) for f in flows])
-    batch = FlowBatch.from_flows(flows, spec)
+    with _obs.span("noc.plan", flows=len(flows)):
+        plan = compile_fabric(topo, [(f.src, f.dsts) for f in flows])
+    with _obs.span("noc.stack", flows=len(flows)):
+        batch = FlowBatch.from_flows(flows, spec)
     fs = expand_fabric(plan, batch, spec, sort_at=sort_at)
     extra_wires = 0
     if spec.codec != "none":
@@ -472,53 +474,58 @@ def _simulate_noc(
     if plan.link_ids:
         # ONE launch over the Q distinct queues; per-link rows are table
         # lookups (dedup'd links carry byte-identical streams)
-        out = bt_count_links(
-            fs.streams,
-            input_lanes=spec.input_lanes,
-            lengths=fs.lengths,
-            interpret=interpret,
-            backend=backend,
-            chunk_rows=chunk_rows,
-            activity_windows=activity_windows,
-        )
-        if activity_windows is not None:
-            qtog, qone = _queue_wire_activity(
-                out, fs.lengths, fs.inverts, activity_windows, extra_wires
+        # activity windows count from the real flits: the longest queue
+        streams = fs.streams if activity_windows is None else (
+            fs.streams[:, : max(max(fs.lengths), 1)])
+        with _obs.span("noc.measure", queues=plan.num_queues):
+            out = bt_count_links(
+                streams,
+                input_lanes=spec.input_lanes,
+                lengths=fs.lengths,
+                interpret=interpret,
+                backend=backend,
+                chunk_rows=chunk_rows,
+                activity_windows=activity_windows,
             )
-            wire_toggles = tuple(qtog[qi] for qi in plan.link_queue)
-            wire_ones = tuple(qone[qi] for qi in plan.link_queue)
-            bt = np.asarray(out.bt)
-        else:
-            bt = np.asarray(out)
-        bt_rows = bt.astype(int).tolist()
-        aux_q = (
-            [0] * plan.num_queues
-            if fs.aux_bt is None
-            else np.asarray(fs.aux_bt).astype(int).tolist()
-        )
-        table = topo.link_table
-        for lid, qi in zip(plan.link_ids, plan.link_queue):
-            length = fs.lengths[qi]
-            bi, bw = bt_rows[qi]
-            aux = aux_q[qi]
-            u, v = int(table[lid, 0]), int(table[lid, 1])
-            stats.append(
-                LinkStats(
-                    link=lid,
-                    src=u,
-                    dst=v,
-                    num_flits=length,
-                    bt_input=bi,
-                    bt_weight=bw,
-                    # same coded-wire accounting as the point-to-point
-                    # path: invert lines switch and widen this hop too
-                    energy_pj=power.coded_hop_energy_pj(
-                        bi + bw, aux, length,
-                        8 * spec.bytes_per_flit, extra_wires,
-                    ),
-                    bt_aux=aux,
+            if activity_windows is not None:
+                qtog, qone = _queue_wire_activity(
+                    out, fs.lengths, fs.inverts, activity_windows, extra_wires
                 )
+                wire_toggles = tuple(qtog[qi] for qi in plan.link_queue)
+                wire_ones = tuple(qone[qi] for qi in plan.link_queue)
+                bt = np.asarray(out.bt)
+            else:
+                bt = np.asarray(out)
+            bt_rows = bt.astype(int).tolist()
+            aux_q = (
+                [0] * plan.num_queues
+                if fs.aux_bt is None
+                else np.asarray(fs.aux_bt).astype(int).tolist()
             )
+        with _obs.span("noc.rollup", links=len(plan.link_ids)):
+            table = topo.link_table
+            for lid, qi in zip(plan.link_ids, plan.link_queue):
+                length = fs.lengths[qi]
+                bi, bw = bt_rows[qi]
+                aux = aux_q[qi]
+                u, v = int(table[lid, 0]), int(table[lid, 1])
+                stats.append(
+                    LinkStats(
+                        link=lid,
+                        src=u,
+                        dst=v,
+                        num_flits=length,
+                        bt_input=bi,
+                        bt_weight=bw,
+                        # same coded-wire accounting as the point-to-point
+                        # path: invert lines switch and widen this hop too
+                        energy_pj=power.coded_hop_energy_pj(
+                            bi + bw, aux, length,
+                            8 * spec.bytes_per_flit, extra_wires,
+                        ),
+                        bt_aux=aux,
+                    )
+                )
     fabric_lat = None
     if latency is not None:
         fabric_lat = fabric_latency(

@@ -27,6 +27,7 @@ from .activity import (
 from .capture import (
     TAP_SCENARIOS,
     CapturedStream,
+    MoeRoute,
     CaptureSession,
     capture,
     capture_lenet_conv,
@@ -90,6 +91,7 @@ __all__ = [
     "write_scenarios_json",
     "TAP_SCENARIOS",
     "CapturedStream",
+    "MoeRoute",
     "CaptureSession",
     "capture",
     "capture_serve_decode",

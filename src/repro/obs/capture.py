@@ -38,8 +38,9 @@ The tap vocabulary (kind -> scenario):
                                       step (the new KV / SSM-state bytes)
   train.grads        train_allreduce  ``train.make_train_step`` after the
                                       gradients are computed
-  moe.dispatch       moe_dispatch     ``models.moe.moe_block`` after the
-                                      expert input buffers are gathered
+  moe.dispatch       moe_dispatch     ``models.forward`` under capture,
+                                      once per expert layer: the layer's
+                                      MoE input rows and their routing
   lenet.conv         lenet_conv       ``models.lenet.lenet_forward``
                                       (trained conv kernels + input batch)
   =================  ===============  =====================================
@@ -47,6 +48,11 @@ The tap vocabulary (kind -> scenario):
 Each recorded stream fires a ``capture.stream`` probe event (bytes per
 scenario/stream) so captures show up in ``obs.collect`` registries and
 ``bench --trace`` timelines.
+
+A ``moe.dispatch`` firing also keeps its arrays where they are, as one
+:class:`MoeRoute` per expert layer in ``CaptureSession.routes``: the rows
+in the compute dtype and the routing, which the expert-parallel
+all-to-all (``repro.noc.moe_dispatch_flows``) is built from.
 """
 
 from __future__ import annotations
@@ -63,6 +69,7 @@ from repro import _obs_hooks
 __all__ = [
     "TAP_SCENARIOS",
     "CapturedStream",
+    "MoeRoute",
     "CaptureSession",
     "capture",
     "capture_serve_decode",
@@ -111,6 +118,19 @@ class CapturedStream:
         return int(self.data.size)
 
 
+@dataclasses.dataclass(frozen=True)
+class MoeRoute:
+    """One expert layer's dispatch as the forward routed it (arrays stay
+    on their device): ``x`` the (T, d) MoE input rows in the compute
+    dtype, ``expert_ids`` the (T, k) experts of each token, ``probs`` the
+    (T, E) float32 router probabilities."""
+
+    layer: int
+    x: object
+    expert_ids: object
+    probs: object
+
+
 def _int8_bytes(arr) -> np.ndarray:
     """A tensor's int8 wire image as 1-D uint8 (already-int8 data passes
     through unquantized — it IS its own wire image)."""
@@ -156,6 +176,7 @@ class CaptureSession:
     def __init__(self, name: str = "capture") -> None:
         self.name = name
         self.streams: list[CapturedStream] = []
+        self.routes: list[MoeRoute] = []  # moe.dispatch firings, in order
 
     # ---------------- recording ----------------
 
@@ -337,14 +358,15 @@ def _extract(kind: str, payload: dict) -> list[tuple]:
         data, n = _tree_bytes(payload["grads"], 1)
         return [("grads", data, (int(data.size),), {"leaves": n})]
     if kind == "moe.dispatch":
-        ei = payload["expert_in"]
-        shape = tuple(int(d) for d in ei.shape)  # (G, E, C, D)
+        x = payload["x"]
         return [
             (
-                "expert_in",
-                _int8_bytes(ei),
-                shape,
-                {"experts": shape[1], "capacity": shape[2]},
+                "x",
+                _int8_bytes(x),
+                tuple(int(d) for d in x.shape),  # (T, d)
+                {"layer": int(payload["layer"]),
+                 "experts": int(payload["probs"].shape[-1]),
+                 "top_k": int(payload["expert_ids"].shape[-1])},
             )
         ]
     # generic: every array-valued payload entry becomes one stream
@@ -373,6 +395,11 @@ class _Tap:
     def tap(self, kind: str, payload: dict) -> None:
         if _has_tracer(payload):
             return  # tracing pass: drop whole payload, touch nothing
+        if kind == "moe.dispatch":
+            route = MoeRoute(int(payload["layer"]), payload["x"],
+                             payload["expert_ids"], payload["probs"])
+            for sess in self.sessions:
+                sess.routes.append(route)
         scenario = TAP_SCENARIOS.get(kind, kind)
         for name, data, shape, meta in _extract(kind, payload):
             for sess in self.sessions:
@@ -504,30 +531,35 @@ def capture_train_step(
 def capture_moe_dispatch(
     cfg,
     *,
+    params=None,
+    tokens=None,
     batch: int = 2,
     seq: int = 16,
     seed: int = 0,
     session: CaptureSession | None = None,
 ) -> CaptureSession:
-    """Run one EAGER MoE block under capture: records the dispatched
-    expert input buffers (the ICI dispatch traffic)."""
-    import jax
-    import jax.numpy as jnp
+    """Run the model's forward under capture: each expert layer records
+    its MoE input rows and routing (``session.routes``, one
+    :class:`MoeRoute` per layer) and their int8 wire image (stream ``x``).
 
-    if cfg.moe is None:
+    ``params`` default to ``init_params`` from the seed, ``tokens`` to
+    (batch, seq) ids drawn uniformly from the seed."""
+    import jax
+
+    if cfg.moe is None or cfg.family != "moe":
         raise ValueError(
             f"config family {cfg.family!r} has no MoE block; "
             "capture_moe_dispatch needs a MoE config"
         )
-    from repro.models.moe import init_moe, moe_block
+    from repro.models import forward, init_params
 
     key = jax.random.key(seed)
-    params = init_moe(key, cfg)
-    x = jax.random.normal(
-        key, (batch, seq, cfg.d_model), jnp.dtype(cfg.dtype)
-    )
+    if params is None:
+        params = init_params(cfg, key)
+    if tokens is None:
+        tokens = jax.random.randint(key, (batch, seq), 0, cfg.vocab)
     with capture(session) as sess:
-        moe_block(params, x, cfg)
+        forward(params, cfg, tokens=tokens)
     return sess
 
 
@@ -565,7 +597,8 @@ def capture_lenet_conv(
 
 def save_session(path: str, session: CaptureSession) -> None:
     """Persist a session's streams as one .npz (bytes + JSON manifest) —
-    the capture->replay artifact (round-trip pinned in tests)."""
+    the capture->replay artifact (round-trip pinned in tests).  The
+    device-resident ``routes`` are not persisted."""
     manifest = [
         {
             "scenario": s.scenario,

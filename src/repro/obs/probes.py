@@ -21,8 +21,16 @@ probe vocabulary:
                             / ``measure_rows`` (each waits on the device)
   link.report        event  ``TxPipeline.measure``/``measure_rows`` —
                             per-stream BT/energy totals
-  noc.expand         span   ``noc.expand_link_streams``
+  noc.expand         span   ``noc.expand_fabric`` (the batched
+                            expansion of every link queue)
   noc.simulate       span   ``noc.simulate_noc``
+  noc.plan           span   ``simulate_noc``'s routing plan
+                            (``compile_fabric``)
+  noc.stack          span   ``simulate_noc``'s flow stacking
+                            (``FlowBatch.from_flows``)
+  noc.measure        span   ``simulate_noc``'s ``bt_count_links`` launch
+                            and the read of its BT table
+  noc.rollup         span   ``simulate_noc``'s per-link ``LinkStats`` loop
   noc.link           event  one per measured NoC link (the per-link BT
                             telemetry behind ``repro.obs.report``)
   noc.contend        event  one per contended link (>= 2 merged flows) of
@@ -30,6 +38,12 @@ probe vocabulary:
   link.activity      event  one per link measured with wire-level
                             activity (``activity_windows=``) — per-wire
                             toggle telemetry (DESIGN.md §15)
+  moe.route          span   ``noc.moe_dispatch_flows`` grouping the
+                            tokens by (source chip, expert) and bringing
+                            the grouped rows to the host
+  moe.load           event  ``noc.moe_dispatch_flows`` — tokens per expert
+                            (max, mean), fabric flows, local flows left
+                            out, bytes on the fabric
   dse.measure        span   each per-width multi-axis launch in
                             ``dse.evaluate_grid``
   dse.readback       span   each host read of a launch's results in
@@ -87,6 +101,12 @@ PROBE_KINDS: dict[str, str] = {
     "link.activity": "event",
     "noc.expand": "span",
     "noc.simulate": "span",
+    "noc.plan": "span",
+    "noc.stack": "span",
+    "noc.measure": "span",
+    "noc.rollup": "span",
+    "moe.route": "span",
+    "moe.load": "event",
     "noc.link": "event",
     "noc.contend": "event",
     "dse.measure": "span",
@@ -107,6 +127,12 @@ _SPAN_LABELS: dict[str, tuple[str, ...]] = {
     "link.stage": ("stage",),
     "noc.expand": ("topology", "sort_at"),
     "noc.simulate": ("topology", "sort_at"),
+    # fabric host phases: their flow / queue / link counts stay trace-only
+    "noc.plan": (),
+    "noc.stack": (),
+    "noc.measure": (),
+    "noc.rollup": (),
+    "moe.route": ("chips", "experts"),
     "dse.measure": ("width",),
     "dse.readback": ("width",),
     "bench.module": ("module",),
@@ -173,6 +199,16 @@ def _record_event(reg: Registry, kind: str, data: dict) -> None:
         hist = reg.histogram("link.activity.wire_toggles", **lab)
         for v in data["per_wire"]:
             hist.observe(v)
+    elif kind == "moe.load":
+        lab = {"chips": data["chips"], "experts": data["experts"]}
+        reg.counter("moe.load.dispatches", **lab).inc()
+        reg.counter("moe.load.flows", **lab).inc(data["flows"])
+        reg.counter("moe.load.local_flows", **lab).inc(data["local_flows"])
+        reg.counter("moe.load.fabric_bytes", **lab).inc(data["fabric_bytes"])
+        reg.gauge("moe.load.tokens_per_expert_max", **lab).set(
+            data["tokens_per_expert_max"])
+        reg.gauge("moe.load.tokens_per_expert_mean", **lab).set(
+            data["tokens_per_expert_mean"])
     elif kind == "dse.link":
         lab = {"link": data["link"], "width": data["width"]}
         reg.counter("dse.link.bt", **lab).inc(data["bt"])

@@ -78,7 +78,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import smoke_config
-from repro.models import decode_step, init_cache, init_params
+from repro.models import decode_step, forward, init_cache, init_params
 from repro.models.lenet import init_lenet, lenet_forward
 from repro.models.moe import init_moe, moe_block
 from repro.optim import AdamWConfig, init as opt_init
@@ -101,6 +101,8 @@ batch = {
 }
 mparams = init_moe(key, mcfg)
 mx = jnp.zeros((2, 8, mcfg.d_model), jnp.dtype(mcfg.dtype))
+dcfg = smoke_config("deepseek-v2-lite")
+dparams = init_params(dcfg, key)
 lparams = init_lenet(key)
 imgs = jnp.zeros((2, 32, 32, 1), jnp.float32)
 
@@ -111,6 +113,9 @@ def jaxprs():
         "train_step": str(jax.make_jaxpr(step)(params, opt, batch)),
         "moe_block": str(jax.make_jaxpr(
             lambda p, x: moe_block(p, x, mcfg))(mparams, mx)),
+        "moe_forward": str(jax.make_jaxpr(
+            lambda p, t: forward(p, dcfg, tokens=t))(
+                dparams, batch["tokens"])),
         "lenet": str(jax.make_jaxpr(lenet_forward)(lparams, imgs)),
     }
 
@@ -184,11 +189,14 @@ def test_capture_train_and_moe_drivers():
     (g,) = grads.get("train_allreduce")
     assert g.kind == "train.grads" and g.num_bytes > 0
 
-    moe = obs.capture_moe_dispatch(
-        smoke_config("qwen3-moe-30b-a3b"), batch=2, seq=8
-    )
-    (e,) = moe.get("moe_dispatch")
-    assert e.name == "expert_in" and len(e.source_shape) == 4
+    mcfg = smoke_config("qwen3-moe-30b-a3b")
+    moe = obs.capture_moe_dispatch(mcfg, batch=2, seq=8)
+    # one stream and one route per expert layer of the forward
+    streams = moe.get("moe_dispatch")
+    assert [e.name for e in streams] == ["x"] * mcfg.n_layers
+    assert all(e.source_shape == (16, mcfg.d_model) for e in streams)
+    assert [r.layer for r in moe.routes] == list(range(mcfg.n_layers))
+    assert moe.routes[0].expert_ids.shape == (16, mcfg.moe.top_k)
     with pytest.raises(ValueError, match="MoE"):
         obs.capture_moe_dispatch(_dense_cfg())
 
@@ -362,28 +370,44 @@ def test_lenet_capture_streams():
 
 
 def test_moe_dispatch_flows_adapter():
+    """A captured route as the expert-parallel all-to-all: every chip of
+    the group sends, each flow is one (source chip, expert) unicast of
+    whole token rows, and a flow to the chip's own expert stays off the
+    fabric."""
     mcfg = smoke_config("qwen3-moe-30b-a3b")
     sess = obs.capture_moe_dispatch(mcfg, batch=2, seq=8)
-    stream = sess.get("moe_dispatch", "expert_in")[0]
-    expert_in = jnp.asarray(
-        stream.data.view(np.int8).reshape(stream.source_shape)
-    )
+    route = sess.routes[0]
     topo = mesh(4, 4)
+    chips = (0, 1, 2, 3, 4, 5, 6, 7)  # 8 experts, one a chip
     spec = LinkSpec(
         width_bits=128, flits_per_packet=4, input_lanes=16, weight_lanes=0,
         key="acc",
     )
-    flows = moe_dispatch_flows(
-        expert_in, topo, 0, tuple(range(1, 16)), spec
+    codes = jnp.asarray(
+        sess.get("moe_dispatch", "x")[0].data.view(np.int8).reshape(16, 64)
     )
-    assert flows and len(flows) <= stream.source_shape[1]
-    assert all(f.src == 0 and len(f.dsts) == 1 for f in flows)
+    with obs.collect() as reg:
+        flows = moe_dispatch_flows(codes, route.expert_ids, topo,
+                                   num_experts=8, chips=chips, spec=spec)
+    ids = np.asarray(route.expert_ids)
+    pairs = {(t // 2, int(e)) for t in range(16) for e in ids[t]}
+    local = sum(s == e for s, e in pairs)
+    assert len(flows) == len(pairs) - local
+    assert all(len(f.dsts) == 1 and f.src != f.dsts[0] for f in flows)
+    assert sum(f.inputs.shape[0] for f in flows) == (
+        sum(int(e) != t // 2 for t in range(16) for e in ids[t]))
+    lab = {"chips": 8, "experts": 8}
+    assert reg.value("moe.load.flows", **lab) == len(flows)
+    assert reg.value("moe.load.local_flows", **lab) == local
+    assert reg.value("moe.route.calls", **lab) == 1
     rep = simulate_noc(topo, flows, spec, sort_at="source")
     assert rep.total_bt > 0
-    with pytest.raises(ValueError, match="groups, experts"):
-        moe_dispatch_flows(expert_in[0], topo, 0, (1,), spec)
+    with pytest.raises(ValueError, match="tokens, d_model"):
+        moe_dispatch_flows(codes[0], route.expert_ids, topo, num_experts=8,
+                           chips=chips, spec=spec)
     with pytest.raises(ValueError, match="weight_lanes=0"):
-        moe_dispatch_flows(expert_in, topo, 0, (1,), LinkSpec(key="acc"))
+        moe_dispatch_flows(codes, route.expert_ids, topo, num_experts=8,
+                           chips=chips, spec=LinkSpec(key="acc"))
 
 
 def test_adapter_int8_passthrough():

@@ -87,6 +87,18 @@ def test_fabric_plan_tables_match_legacy_queue_semantics():
     )
 
 
+def test_flow_batch_stacks_flows_back_to_back():
+    """Flows of 3, 5, 7 and 9 packets fill 24 rows of a 32-row batch in
+    flow order: no flow is padded to the longest."""
+    flows = _flows(ring(6))
+    batch = FlowBatch.from_flows(flows, LinkSpec())
+    assert batch.counts == (3, 5, 7, 9) and batch.starts == (0, 3, 8, 15)
+    assert batch.inputs.shape == (32, 32) == batch.weights.shape
+    want = np.concatenate([np.asarray(f.inputs) for f in flows])
+    np.testing.assert_array_equal(np.asarray(batch.inputs[:24]), want)
+    assert not np.asarray(batch.inputs[24:]).any()
+
+
 def test_fabric_plan_rejects_batch_size_mismatch():
     topo = ring(6)
     flows = _flows(topo, n=2)

@@ -99,6 +99,6 @@ def test_shape_assignment():
         assert {"train_4k", "prefill_32k", "decode_32k"} <= set(shapes)
         fam = get_config(arch).family
         assert ("long_500k" in shapes) == (fam in ("ssm", "hybrid"))
-    # 40 nominal cells minus 8 documented long_500k skips
+    # 44 nominal cells (11 archs) minus 9 documented long_500k skips
     total = sum(len(arch_shapes(a)) for a in ARCH_NAMES)
-    assert total == 32
+    assert total == 35

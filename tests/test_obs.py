@@ -276,6 +276,35 @@ def test_noc_link_counters_match_report():
     assert sum(r["gross_bt"] for r in table) == rep.gross_bt
 
 
+def test_noc_host_phase_spans_under_profiling(monkeypatch):
+    """A profiled ``simulate_noc`` enters one profiler annotation per host
+    phase, nested in ``noc.simulate``: planning, stacking, the expansion,
+    the measurement and its read, and the per-link roll-up, in order."""
+    from repro.obs import probes
+
+    entered = []
+
+    class Recorder:
+        def __init__(self, name, **labels):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(probes, "TraceAnnotation", Recorder)
+    x = _packets(elems=_input_spec().elems_per_packet, seed=3)
+    flows = [TrafficFlow("f0", 0, (3,), x), TrafficFlow("f1", 1, (2,), x)]
+    with obs.profiling():
+        simulate_noc(mesh(2, 2), flows, _input_spec(), backend="compiled")
+    noc = [k for k in entered if k.startswith("noc.")]
+    assert noc == ["noc.simulate", "noc.plan", "noc.stack", "noc.expand",
+                   "noc.measure", "noc.rollup"]
+    assert "kernel.dispatch" in entered
+
+
 def test_top_links_ordering_and_format(tmp_path):
     reg, rep = _noc_run()
     top = obs.top_links(reg, 2)
