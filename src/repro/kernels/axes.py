@@ -265,7 +265,8 @@ def _pack_side(values, ln, flits, pack, column_major):
             column_major,
         )
         sel = lax.broadcasted_iota(jnp.int32, shape, 0) == src
-        values = _onehot_dot(values, sel, ((1,), (0,)))
+        # byte payloads x a 0/1 permutation selector
+        values = _onehot_dot(values, sel, ((1,), (0,)), bounds=(255, 1))
     if flits == 1:
         return values
     return jnp.stack(
@@ -298,7 +299,8 @@ def _axes_block(
     the SAME traced operations, so they are bit-exact by construction.
 
     Args:
-      x / w: (BP, N) int32 packet payloads of this block.
+      x / w: (BP, N) int32 packet payloads of this block: bytes (the
+        selector products' bounds rest on it, ``psu._onehot_dot``).
       remaining_rows: int32 scalar — this link's valid flit rows minus the
         rows consumed by earlier blocks (may be <= 0: fully-padded block).
       start_row: int32 scalar — global flit-row index of this block's first
@@ -355,8 +357,11 @@ def _axes_block(
 
         def _wire_bits(arr):  # (T, lanes) bytes -> (T, lanes*8) bits
             # wire = lane*8 + bit, LSB first: copy each byte onto its 8
-            # wires with one selector product, then pick each wire's bit
-            per_wire = _onehot_dot(arr, lane_to_wire, ((1,), (1,)))
+            # wires with one selector product (bytes x the 0/1 lane-to-wire
+            # map), then pick each wire's bit
+            per_wire = _onehot_dot(
+                arr, lane_to_wire, ((1,), (1,)), bounds=(255, 1)
+            )
             return (per_wire >> bit_of_wire) & 1
 
         rmask = (row_idx < valid).astype(jnp.int32)  # (rows, 1) levels
@@ -370,7 +375,11 @@ def _axes_block(
         win_onehot = (bwin == win_iota).astype(jnp.int32)
 
         def _scatter(toggles):  # (rows-1, W) 0/1 -> (NW, W) window counts
-            return _onehot_dot(win_onehot, toggles, ((0,), (0,)))
+            # 0/1 window one-hot x 0/1 toggles, summed over the rows - 1
+            # boundaries (far below 2^24)
+            return _onehot_dot(
+                win_onehot, toggles, ((0,), (0,)), bounds=(1, 1)
+            )
 
         acts, ones_rows = [], []
 
@@ -407,13 +416,14 @@ def _axes_block(
             if emit_stream:
                 iota_i = lax.broadcasted_iota(jnp.int32, (bp, n), 1)
                 rows_payload = [iota_i] + rows_payload
-            moved = lax.dot_general(
-                jnp.stack(rows_payload, axis=1).astype(jnp.float32),
-                perm.astype(jnp.float32),
-                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-                precision=lax.Precision.HIGHEST,
-            ).astype(jnp.int32)  # (BP, 1|2|3, N)
+            # bytes (and positions below N) x the 0/1 permutation matrix
+            moved = _onehot_dot(
+                jnp.stack(rows_payload, axis=1),
+                perm,
+                ((2,), (1,)),
+                batch=((0,), (0,)),
+                bounds=(max(255, n - 1) if emit_stream else 255, 1),
+            )  # (BP, 1|2|3, N)
             # static row picks (a negative index would lower as a
             # dynamic_slice, which Mosaic has no rule for)
             picked = [
@@ -495,8 +505,9 @@ def _axes_block(
             grp = _group_matrix(lanes, pw)  # (lanes, npart) lane -> group
             dx = stream[1:] ^ stream[:-1]  # (rows-1, lanes)
             dpc = _popcount_bits(dx, 8)
+            # per-lane popcounts (<= 8) x the 0/1 lane-to-group map
             v0, v1 = _bus_invert_bits(
-                _onehot_dot(dpc, grp, ((1,), (0,))), lbits
+                _onehot_dot(dpc, grp, ((1,), (0,)), bounds=(8, 1)), lbits
             )
             # input/weight lane split: global lane id < split_lanes
             in_mask = (
@@ -505,8 +516,12 @@ def _axes_block(
             ones_col = jnp.ones((rows - 1, 1), jnp.int32)
 
             def _per_part(arr):  # (rows-1, lanes) -> (npart, 1) group sums
+                # 0/1 groups x per-lane flips (<= 8 a row) summed over the
+                # rows - 1 boundaries: past 33 rows the bound exceeds 256
+                # and the product keeps f32 HIGHEST
                 return _onehot_dot(
-                    grp, arr.sum(axis=0, keepdims=True), ((0,), (1,))
+                    grp, arr.sum(axis=0, keepdims=True), ((0,), (1,)),
+                    bounds=(1, 8 * (rows - 1)),
                 )
 
             parts, edges, inv_edges = [], [], []
@@ -515,20 +530,29 @@ def _axes_block(
                 grp8 = _group_matrix(lanes, pw, 8)  # (lanes*8, npart)
             for v in (v0, v1):
                 e = v[1:] ^ v[:-1]  # (rows-1, npart) invert-line flips
-                e_lane = _onehot_dot(e, grp, ((1,), (1,)))  # (rows-1, lanes)
+                # 0/1 invert-line flips x the 0/1 group map
+                e_lane = _onehot_dot(
+                    e, grp, ((1,), (1,)), bounds=(1, 1)
+                )  # (rows-1, lanes)
                 lane_flips = jnp.where(e_lane == 1, 8 - dpc, dpc) * bmask
                 parts.append(jnp.concatenate([
                     _per_part(lane_flips * in_mask),
                     _per_part(lane_flips * (1 - in_mask)),
-                    _onehot_dot(e * bmask, ones_col, ((0,), (0,))),
+                    # 0/1 flips summed over rows - 1 (far below 2^24)
+                    _onehot_dot(
+                        e * bmask, ones_col, ((0,), (0,)), bounds=(1, 1)
+                    ),
                 ], axis=1))  # (npart, 3)
-                wire = stream ^ (_onehot_dot(v, grp, ((1,), (1,))) * 0xFF)
+                # 0/1 invert-line states x the 0/1 group map
+                inv = _onehot_dot(v, grp, ((1,), (1,)), bounds=(1, 1))
+                wire = stream ^ (inv * 0xFF)
                 edges.append(_edges(wire))
                 inv_edges.append(_pad_lanes(_edges(v), pmax))
                 if act_on:
                     # wire-bit toggle = data-bit toggle XOR its partition's
                     # invert-line flip; the invert line itself is a wire
-                    erep = _onehot_dot(e, grp8, ((1,), (1,)))
+                    # (0/1 flips x the 0/1 wire-to-group map)
+                    erep = _onehot_dot(e, grp8, ((1,), (1,)), bounds=(1, 1))
                     tb = (_wire_bits(dx) ^ erep) * bmask
                     aux_t = _pad_lanes(e * bmask, pmax)
                     acts_b.append(

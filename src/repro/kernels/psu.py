@@ -54,18 +54,53 @@ def _popcount_bits(x: jax.Array, width: int) -> jax.Array:
     return v & jnp.int32(0x1F)
 
 
-def _onehot_dot(a: jax.Array, sel: jax.Array, dims) -> jax.Array:
-    """Exact integer contraction of ``a`` with a 0/1 selector on the MXU.
+# bfloat16 holds every integer of magnitude <= 256 exactly (8-bit significand)
+_ONE_PASS_BOUND = 256
 
-    Every operand here is a small integer (bytes, counts, indices), so an
-    f32 product at HIGHEST precision is exact; the result is int32.
+
+def _mxu_operand(x: jax.Array, bound: int, one_pass: bool) -> jax.Array:
+    """``x`` as an f32 product operand.  A 0/1 operand (``bound`` 1) is
+    exact in any dtype and is only cast; on the one-pass path a larger
+    one is rounded through bfloat16, as the chip's single pass rounds it,
+    so a CPU run (whose f32 product is exact) sees any wrong bound too."""
+    x = x.astype(jnp.float32)
+    if one_pass and bound > 1:
+        x = x.astype(jnp.bfloat16).astype(jnp.float32)
+    return x
+
+
+def _onehot_dot(
+    a: jax.Array, b: jax.Array, dims, *, bounds, batch=((), ())
+) -> jax.Array:
+    """Exact integer contraction of ``a`` and ``b`` on the MXU, as int32.
+
+    ``bounds`` states the largest magnitude of ``a``'s and of ``b``'s values
+    (static: bytes 255, 0/1 masks 1, popcounts 8, positions N - 1).  Where
+    both are at most 256 every value is exact in bfloat16, so ONE bf16 MXU
+    pass with f32 accumulation gives the exact integers (DEFAULT precision:
+    one pass on the TPU, exact f32 on the CPU); a larger bound keeps the
+    multi-pass f32 HIGHEST product.  Either way each output must sum to
+    below 2^24, where f32 accumulation is exact; that is checked here from
+    the bounds and the contracted size.
     """
+    bound_a, bound_b = bounds
+    terms = 1
+    for d in dims[0]:
+        terms *= a.shape[d]
+    if bound_a * bound_b * terms >= 1 << 24:
+        raise ValueError(
+            f"inexact contraction: {terms} terms of |a| <= {bound_a}, "
+            f"|b| <= {bound_b} can reach 2^24"
+        )
+    one_pass = max(bounds) <= _ONE_PASS_BOUND
     return lax.dot_general(
-        a.astype(jnp.float32),
-        sel.astype(jnp.float32),
-        dimension_numbers=(dims, ((), ())),
+        _mxu_operand(a, bound_a, one_pass),
+        _mxu_operand(b, bound_b, one_pass),
+        dimension_numbers=(dims, batch),
         preferred_element_type=jnp.float32,
-        precision=lax.Precision.HIGHEST,
+        precision=(
+            lax.Precision.DEFAULT if one_pass else lax.Precision.HIGHEST
+        ),
     ).astype(jnp.int32)
 
 
@@ -89,10 +124,12 @@ def _rank_from_keys(key: jax.Array, nb: int) -> jax.Array:
         lax.broadcasted_iota(jnp.int32, (n, n), 1)
     )
     hits = [key == b for b in range(nb)]
+    # 0/1 bucket one-hots x the 0/1 [j < i] matrix; each count is < N
     within = _onehot_dot(
         jnp.concatenate([h.astype(jnp.float32) for h in hits], axis=0),
         earlier,
         ((1,), (0,)),
+        bounds=(1, 1),
     )  # (nb*BP, N): earlier elements in the same bucket
     last = key[:, n - 1:]
     rank = jnp.zeros_like(key)
