@@ -31,7 +31,7 @@ def _structured_weight(rng, ff, d):
     return jnp.asarray(rng.normal(size=(ff, d)) * rng.lognormal(0, 1.0, (ff, 1)))
 
 
-def run() -> list[tuple[str, float, str]]:
+def run() -> list[tuple[str, str]]:
     rows = []
     rng = np.random.default_rng(0)
 
@@ -42,7 +42,7 @@ def run() -> list[tuple[str, float, str]]:
             for strat in ("none", "acc", "app"):
                 rep = stream_bt_report("w", w, strat, sign_magnitude=sm, layout=layout)
                 rows.append((
-                    f"arch_bt/weights/sm={int(sm)}/{layout}/{strat}", 0.0,
+                    f"arch_bt/weights/sm={int(sm)}/{layout}/{strat}",
                     f"bt/flit={rep.bt_ordered / rep.num_flits:.2f} "
                     f"red_vs_unordered={rep.reduction * 100:.2f}%",
                 ))
@@ -60,7 +60,7 @@ def run() -> list[tuple[str, float, str]]:
         )
         rep = stream_bt_report(arch, tensor, "app", sign_magnitude=True, layout="col")
         rows.append((
-            f"arch_bt/{arch}/mlp_stream", 0.0,
+            f"arch_bt/{arch}/mlp_stream",
             f"bt_base={rep.bt_none} bt_app={rep.bt_ordered} "
             f"red={rep.reduction * 100:.2f}% (iid-init rows: expected ~0)",
         ))
@@ -80,7 +80,7 @@ def run() -> list[tuple[str, float, str]]:
     ).measure_rows(t8, "moe_dispatch")
     ordered = TxPipeline(dispatch_spec).measure_rows(t8, "moe_dispatch")
     rows.append((
-        "arch_bt/moe_dispatch/app", 0.0,
+        "arch_bt/moe_dispatch/app",
         f"bt_base={base.total_bt} bt_ordered={ordered.total_bt} "
         f"red={100 * (1 - ordered.total_bt / base.total_bt):.2f}%",
     ))
@@ -92,7 +92,7 @@ def run() -> list[tuple[str, float, str]]:
     base = int(bt_count(tensor_flit_stream(g)))
     permuted = int(bt_count(tensor_flit_stream(g[jnp.asarray(perm)])))
     rows.append((
-        "arch_bt/grad_egress/static_perm", 0.0,
+        "arch_bt/grad_egress/static_perm",
         f"bt_base={base} bt_perm={permuted} red={100 * (1 - permuted / base):.2f}% "
         "(uncorrelated grads: expected ~0 — recorded as the honest negative "
         "result; value-dependent per-step sorting would desynchronise the "

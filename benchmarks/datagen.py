@@ -1,6 +1,6 @@
 """Synthetic traffic generators shared by the paper-reproduction benches.
 
-Two data models (EXPERIMENTS.md §Table I discusses why both are needed):
+Two data models, both needed:
 
   * ``uniform``  — the paper's literal "random inputs and weights": iid
     uniform bytes.  Analytically, popcount ordering's gain is bounded here

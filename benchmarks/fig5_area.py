@@ -14,7 +14,7 @@ PAPER = {("app", 25): 2193.0, ("app", 49): 6928.0, "overall_reduction": 35.4}
 TINY_KWARGS = {"ns": (25,)}  # CI smoke (REPRO_BENCH_TINY=1): one sort width
 
 
-def run(ns: tuple[int, ...] = (25, 49)) -> list[tuple[str, float, str]]:
+def run(ns: tuple[int, ...] = (25, 49)) -> list[tuple[str, str]]:
     rows = []
     for n in ns:
         designs = {
@@ -25,13 +25,13 @@ def run(ns: tuple[int, ...] = (25, 49)) -> list[tuple[str, float, str]]:
         }
         for name, a in designs.items():
             rows.append((
-                f"fig5/N{n}/{name}", 0.0,
+                f"fig5/N{n}/{name}",
                 f"popcount={a.popcount:.0f}um2 sort={a.sort:.0f}um2 "
                 f"total={a.total:.0f}um2",
             ))
         acc, app = designs["acc_psu"], designs["app_psu"]
         rows.append((
-            f"fig5/N{n}/reductions", 0.0,
+            f"fig5/N{n}/reductions",
             f"overall={100 * (1 - app.total / acc.total):.1f}% "
             f"popcount={100 * (1 - app.popcount / acc.popcount):.1f}% "
             f"sort={100 * (1 - app.sort / acc.sort):.1f}% "
@@ -44,7 +44,7 @@ def run(ns: tuple[int, ...] = (25, 49)) -> list[tuple[str, float, str]]:
     for pt in k_sweep(n=25, width=8, ks=(2, 4, 8),
                       include_baseline=False, include_precise=False):
         a = pt.area()
-        rows.append((f"fig5/k_sweep/k{pt.k}", 0.0, f"total={a.total:.0f}um2"))
+        rows.append((f"fig5/k_sweep/k{pt.k}", f"total={a.total:.0f}um2"))
 
     # timing model at the paper's 500 MHz target (latency scaling argument)
     from repro.core import bitonic_timing, psu_timing
@@ -52,7 +52,7 @@ def run(ns: tuple[int, ...] = (25, 49)) -> list[tuple[str, float, str]]:
     for n in ns:
         acc, app, bit = psu_timing(n), psu_timing(n, k=4), bitonic_timing(n)
         rows.append((
-            f"fig5/timing/N{n}", 0.0,
+            f"fig5/timing/N{n}",
             f"acc={acc.sort_time_ns(n):.0f}ns app={app.sort_time_ns(n):.0f}ns "
             f"bitonic_latency={bit.latency_cycles}cyc vs psu "
             f"{acc.latency_cycles}cyc (O(1) in N)",

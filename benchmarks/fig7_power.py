@@ -23,7 +23,7 @@ LINK_SHARE = 4.98 / 18.27  # PE-level share of link-related power (Fig. 6)
 TINY_KWARGS = {"conv_images": 2}  # CI smoke (REPRO_BENCH_TINY=1)
 
 
-def run(conv_images: int = 24) -> list[tuple[str, float, str]]:
+def run(conv_images: int = 24) -> list[tuple[str, str]]:
     model = LinkPowerModel()
     inp, wgt = conv_streams(n_images=conv_images)
     base = _measure_separate(inp, "none") + _measure_separate(wgt, "none")
@@ -35,7 +35,7 @@ def run(conv_images: int = 24) -> list[tuple[str, float, str]]:
         pe_red = LINK_SHARE * link_red * 100
         p = PAPER[strat]
         rows.append((
-            f"fig7/{strat}", 0.0,
+            f"fig7/{strat}",
             f"bt_red={bt_red * 100:.2f}% (paper {p['bt']}%) "
             f"link_power_red={link_red * 100:.2f}% (paper {p['link_power']}%) "
             f"pe_power_red={pe_red:.2f}% (paper {p['pe_power']}%)",
@@ -47,7 +47,7 @@ def run(conv_images: int = 24) -> list[tuple[str, float, str]]:
 
     app_red = area_reduction(DesignPoint(n=25, width=8, k=4, ordering="app"))
     rows.append((
-        "fig7/psu_power_overhead", 0.0,
+        "fig7/psu_power_overhead",
         f"app/acc area ratio={1 - app_red:.3f} -> overhead "
         f"reduction={100 * app_red:.1f}% (paper 37.3% "
         "power, 35.4% area)",

@@ -17,7 +17,6 @@ per stream — DESIGN.md §11).
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -57,7 +56,7 @@ def _pipes() -> dict[str, TxPipeline]:
     }
 
 
-def run(n_images: int = 6) -> list[tuple[str, float, str]]:
+def run(n_images: int = 6) -> list[tuple[str, str]]:
     rng = np.random.default_rng(0)
     imgs = synth_images(n_images, seed=7)
     kernels = rng.integers(0, 256, (N_CH, KERNEL * KERNEL), dtype=np.uint8)
@@ -65,8 +64,6 @@ def run(n_images: int = 6) -> list[tuple[str, float, str]]:
 
     rows = []
     total_bt = {"none": 0, "acc": 0, "app": 0}
-    t_psu = 0.0
-    n_packets = 0
     in_streams, w_streams = [], []
     for img in imgs:
         patches = im2col(img, KERNEL)  # (P, 25) uint8
@@ -78,10 +75,7 @@ def run(n_images: int = 6) -> list[tuple[str, float, str]]:
         w = jnp.asarray(flat_w[: p * ELEMS].reshape(p, ELEMS))
         in_streams.append(x)
         w_streams.append(w)
-        t0 = time.monotonic()
         res = {name: pipes[name].run(x) for name in ("acc", "app")}
-        t_psu += time.monotonic() - t0
-        n_packets += p
         total_bt["none"] += int(pipes["none"].run(x).bt_input)
         for name, r in res.items():
             total_bt[name] += int(r.bt_input)
@@ -99,7 +93,7 @@ def run(n_images: int = 6) -> list[tuple[str, float, str]]:
     for name in ("acc", "app"):
         red = 100 * (1 - total_bt[name] / total_bt["none"])
         rows.append((
-            f"lenet/{name}", t_psu * 1e6 / max(n_packets, 1),
+            f"lenet/{name}",
             f"bt={total_bt[name]} base={total_bt['none']} red={red:.2f}% "
             f"(paper link-BT red: acc 20.42% app 19.50%)",
         ))
@@ -107,7 +101,6 @@ def run(n_images: int = 6) -> list[tuple[str, float, str]]:
     # --- ordering vs coding vs composed on the same LeNet streams ---
     # (repro.codec.compare: one bt_count_codecs launch per stream; both
     # links of the conv scenario — patch packets and kernel bytes — summed)
-    t0 = time.monotonic()
     table = compare_streams(
         in_streams + w_streams,
         LANES,
@@ -115,10 +108,9 @@ def run(n_images: int = 6) -> list[tuple[str, float, str]]:
         codecs=("none", "bus_invert4"),
         workload="lenet",
     )
-    us = (time.monotonic() - t0) * 1e6 / len(table)
     for r in table:
         rows.append((
-            f"lenet/compare/{r.label}", us,
+            f"lenet/compare/{r.label}",
             f"data_bt={r.data_bt} aux_bt={r.aux_bt} wires=+{r.extra_wires} "
             f"net_red={100 * r.bt_reduction:.2f}%",
         ))

@@ -22,7 +22,7 @@ Each scenario's captured workload runs through ``dse.evaluate_grid``
 through ``noc.simulate`` on a fabric via the matching ``noc.adapters``
 flow builder, with per-link telemetry collected by ``repro.obs``.  The
 campaign lands as ``SCENARIOS_model_traffic.csv`` / ``.json`` artifacts
-(``repro.obs.report.scenario_table``) next to the bench JSON, and the
+(``repro.obs.report.scenario_table``) in the current directory, and the
 trained-weight recalibration rows report captured overall reductions SIDE
 BY SIDE with the §10 synthetic numbers and the paper's — never
 substituted.
@@ -31,8 +31,6 @@ substituted.
 from __future__ import annotations
 
 import os
-import time
-from contextlib import nullcontext
 
 import jax.numpy as jnp
 import numpy as np
@@ -133,7 +131,7 @@ def run(
     new_tokens: int = 4,
     seq: int = 32,
     activity_windows: int = 32,
-) -> list[tuple[str, float, str]]:
+) -> list[tuple[str, str]]:
     from repro.configs import smoke_config
     from repro.models import lenet
 
@@ -143,18 +141,15 @@ def run(
 
     # ---- lenet_conv: train (or restore) the real model, capture, measure
     ckpt_dir = os.environ.get("REPRO_LENET_CKPT", ".lenet_ckpt")
-    t0 = time.monotonic()
     params, info = lenet.train_lenet(steps=lenet_steps, ckpt_dir=ckpt_dir)
     rows.append((
         "model/lenet/train",
-        (time.monotonic() - t0) * 1e6,
         f"steps={info['steps']} final_loss={info['final_loss']:.4f} "
         f"restored={int(info['restored'])} ckpt={ckpt_dir}",
     ))
     sessions = {"lenet_conv": obs.capture_lenet_conv(params=params)}
 
     # ---- serve_decode / train_allreduce / moe_dispatch: eager captures
-    t0 = time.monotonic()
     sessions["serve_decode"] = obs.capture_serve_decode(
         smoke_config(SERVE_ARCH), batch=batch, prompt=prompt,
         new_tokens=new_tokens,
@@ -165,10 +160,8 @@ def run(
     sessions["moe_dispatch"] = obs.capture_moe_dispatch(
         smoke_config(MOE_ARCH), batch=batch, seq=seq
     )
-    capture_us = (time.monotonic() - t0) * 1e6
     rows.append((
         "model/capture",
-        capture_us,
         "scenarios=4 streams="
         + " ".join(
             f"{k}:{len(s.streams)}" for k, s in sorted(sessions.items())
@@ -224,9 +217,7 @@ def run(
     # ---- per-scenario DSE grid over the captured workloads
     for scenario in sorted(sessions):
         sess = sessions[scenario]
-        t0 = time.monotonic()
         wl, evals = _evaluate(sess, scenario, activity_windows)
-        us = (time.monotonic() - t0) * 1e6
         noc_red, hot_link, _ = noc_results[scenario]
         records.append(
             _record(scenario, sess, evals, float(noc_red), hot_link)
@@ -234,7 +225,6 @@ def run(
         base, acc, app, comp = evals
         rows.append((
             f"model/{scenario}/bt",
-            us,
             f"streams={len(wl.streams)} flits={wl.num_flits} "
             f"bt_base={base.total_bt} red_acc={100 * acc.bt_reduction:.2f}% "
             f"red_app={100 * app.bt_reduction:.2f}% "
@@ -243,7 +233,6 @@ def run(
         ))
         rows.append((
             f"model/{scenario}/noc",
-            0.0,
             f"fabric_red_acc={100 * noc_red:.2f}% hot_link="
             + (
                 f"{hot_link['src']}->{hot_link['dst']} "
@@ -277,7 +266,6 @@ def run(
         }
         rows.append((
             f"model/recalib/{strat}",
-            0.0,
             f"captured_red={red:.2f}% "
             f"synthetic_red={SYNTHETIC_OVERALL[strat]}% "
             f"paper_red={PAPER_OVERALL[strat]}% (trained LeNet streams)",
@@ -292,7 +280,6 @@ def run(
     )
     rows.append((
         "model/artifact",
-        0.0,
         f"{len(records)} scenario records -> {csv_path} + {json_path}",
     ))
     return rows

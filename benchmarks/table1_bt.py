@@ -6,7 +6,7 @@ Paper values (for reference, 100k packets of paired random data):
 
 We report both data models (see datagen.py): the paper's reductions are
 reproduced on the conv-traffic model; uniform iid bytes show the analytic
-~5 % ceiling for paired framing (derivation in EXPERIMENTS.md §Table I).
+~5 % ceiling for paired framing (derivation in datagen.py).
 
 All measurements run through ``repro.link.TxPipeline``; ACC/APP take the
 fused single-launch kernel path, 'none'/'column_major' the staged path
@@ -14,8 +14,6 @@ fused single-launch kernel path, 'none'/'column_major' the staged path
 """
 
 from __future__ import annotations
-
-import time
 
 import jax.numpy as jnp
 
@@ -30,8 +28,8 @@ PAPER = {
     "app": (50.896, 19.305),
 }
 # input-side BT/flit from Table I (the stream the PSU actually orders);
-# the weight-stream generation is underspecified in the paper (see
-# EXPERIMENTS.md §Table I), so the input side is the calibration target.
+# the weight-stream generation is underspecified in the paper, so the input
+# side is the calibration target.
 # The conv weight stream cycles the layer's 6 output-channel kernels
 # (DESIGN.md §10 recalibration: overall ACC 14.2 % / APP 12.7 % vs the
 # paper's 20.42 % / 19.50 % — reported side by side, never substituted).
@@ -60,20 +58,18 @@ def _measure_separate(vals, strat, lanes=16, k=4):
     return pipe.measure(x).overall_bt_per_flit
 
 
-def run(packets: int = 20000, conv_images: int = 24) -> list[tuple[str, float, str]]:
+def run(packets: int = 20000, conv_images: int = 24) -> list[tuple[str, str]]:
     rows = []
 
     # --- paired uniform framing (paper's literal setup) ---
     inp, wgt = uniform_pairs(packets, LinkSpec().elems_per_packet)
     inp, wgt = jnp.asarray(inp), jnp.asarray(wgt)
-    t0 = time.monotonic()
     base = TxPipeline(LinkSpec(key="none")).measure(inp, wgt)
     for strat in STRATS:
         r = TxPipeline(LinkSpec(key=strat)).measure(inp, wgt)
         red = r.reduction_vs(base) * 100
         rows.append((
             f"table1/uniform/{strat}",
-            (time.monotonic() - t0) * 1e6 / packets,
             f"bt_per_flit={r.overall_bt_per_flit:.3f} red={red:.2f}% "
             f"fused={int(r.fused)} "
             f"paper_bt={PAPER[strat][0]} paper_red={PAPER[strat][1]}%",
@@ -82,7 +78,6 @@ def run(packets: int = 20000, conv_images: int = 24) -> list[tuple[str, float, s
     # --- conv-traffic model (reproduces the paper's magnitudes) ---
     inp, wgt = conv_streams(n_images=conv_images)
     inp_cm, wgt_cm = conv_streams(n_images=conv_images, column_major=True)
-    t0 = time.monotonic()
     base_i = _measure_separate(inp, "none")
     base_w = _measure_separate(wgt, "none")
     for strat in STRATS:
@@ -99,7 +94,6 @@ def run(packets: int = 20000, conv_images: int = 24) -> list[tuple[str, float, s
         paper_in_red = 100 * (1 - PAPER_INPUT[strat] / PAPER_INPUT["none"])
         rows.append((
             f"table1/conv/{strat}",
-            (time.monotonic() - t0) * 1e6 / inp.shape[0],
             f"in={bi:.3f} (paper {PAPER_INPUT[strat]}) wt={bw:.3f} "
             f"overall_red={red:.2f}% input_red={in_red:.2f}% "
             f"(paper input_red={paper_in_red:.2f}%)",
@@ -112,7 +106,6 @@ def run(packets: int = 20000, conv_images: int = 24) -> list[tuple[str, float, s
     red_app = 1 - (app_i + app_w) / (base_i + base_w)
     rows.append((
         "table1/conv/app_retention",
-        0.0,
         f"app/acc={100 * red_app / red_acc:.1f}% (paper 95.5%)",
     ))
 
@@ -121,7 +114,7 @@ def run(packets: int = 20000, conv_images: int = 24) -> list[tuple[str, float, s
     for k in (2, 4, 8):
         bi = _measure_separate(inp, "app", k=k)
         rows.append((
-            f"table1/conv/k_sweep/k{k}", 0.0,
+            f"table1/conv/k_sweep/k{k}",
             f"input_bt={bi:.3f} input_red={100 * (1 - bi / base_i):.2f}% "
             f"(acc={100 * (1 - acc_i / base_i):.2f}%)",
         ))

@@ -75,7 +75,7 @@ def main() -> None:
             print(f"  sign_magnitude={sm!s:5s} order={strat:4s} "
                   f"BT/flit={rep.overall_bt_per_flit:6.2f}")
     print("(sign-magnitude recoding ~halves BT; ordering adds a few % on "
-          "magnitude-structured rows — EXPERIMENTS.md §Arch-BT)")
+          "magnitude-structured rows — benchmarks/arch_bt.py)")
 
 
 if __name__ == "__main__":

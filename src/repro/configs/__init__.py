@@ -3,8 +3,9 @@ reduced smoke variants.
 
 ``get_config(name)`` returns the exact assigned configuration;
 ``smoke_config(name)`` returns the same *family* at toy scale (few layers,
-narrow width, tiny vocab/experts) for CPU smoke tests.  The FULL configs are
-exercised only through the dry-run (ShapeDtypeStruct, no allocation).
+narrow width, tiny vocab/experts) for CPU smoke tests.  The FULL configs'
+shapes are checked abstractly (``param_shapes``: ShapeDtypeStruct, no
+allocation).
 """
 
 from __future__ import annotations

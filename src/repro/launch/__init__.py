@@ -1,6 +1,4 @@
-# Launch layer: production mesh, sharding rules, dry-run specs.
-# NOTE: repro.launch.dryrun must be imported/run as the entry point BEFORE
-# other jax use (it sets the 512-device XLA flag); import it lazily.
+# Launch layer: production mesh and sharding rules.
 from .mesh import axis_size, dp_axes, make_production_mesh, make_smoke_mesh
 from .sharding import (
     batch_shardings,
@@ -9,7 +7,6 @@ from .sharding import (
     param_spec,
     params_shardings,
 )
-from .specs import DryrunCase, build_case
 
 __all__ = [
     "make_production_mesh",
@@ -21,6 +18,4 @@ __all__ = [
     "opt_shardings",
     "batch_shardings",
     "cache_shardings",
-    "build_case",
-    "DryrunCase",
 ]

@@ -1,9 +1,9 @@
 """Production mesh construction.
 
 Defined as FUNCTIONS (no module-level device access) so importing this
-module never initialises jax device state — the dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any import,
-smoke tests see the real single device.
+module never initialises jax device state: a caller that wants a forced
+host device count (``XLA_FLAGS=--xla_force_host_platform_device_count=N``)
+sets it before any import, and smoke tests see the real single device.
 """
 
 from __future__ import annotations

@@ -12,8 +12,7 @@ Strategy (DESIGN.md §5):
                        axis of activations/caches on "model"
 
 Every rule guards divisibility and falls back to replication, so every
-(arch x shape x mesh) cell is *legal by construction*; the roofline then
-shows what the fallbacks cost.
+(arch x shape x mesh) cell is *legal by construction*.
 """
 
 from __future__ import annotations
@@ -54,8 +53,6 @@ def param_spec(
     path = _norm_path(path)
     name = path.rsplit(".", 1)[-1]
     rank = len(shape)
-    if cfg.pure_dp:
-        return P()  # replicate everything; batch shards over all axes
     if cfg.fsdp and mode == "train":
         return _fsdp_spec(path, name, shape, cfg, mesh)
 
@@ -88,8 +85,7 @@ def param_spec(
                 return _pad_rank((None, "model", None), rank)
             # GQA with kv-heads < TP degree: REPLICATE the (small) kv
             # projections — d-contraction sharding costs an all-gather-heavy
-            # backward (measured: +155 GB/device collectives on internlm2
-            # train_4k; see EXPERIMENTS.md §Perf)
+            # backward
             return P()
         if name == "wo":
             h, hd, d = shape[-3:]
@@ -243,8 +239,6 @@ def opt_shardings(cfg: ModelConfig, mesh, opt_shapes: Any, params_shapes: Any) -
 
 def batch_shardings(cfg: ModelConfig, mesh, batch_shapes: dict) -> dict:
     dp = dp_axes(mesh)
-    if cfg.pure_dp:
-        dp = tuple(mesh.axis_names)  # batch over every axis incl. "model"
     dpn = int(np.prod([mesh.shape[a] for a in dp]))
 
     def leaf(x):
@@ -267,12 +261,8 @@ def cache_shardings(cfg: ModelConfig, mesh, cache_shapes: dict) -> dict:
     conv: (L, B, K, C) -> B over DP, C over model
     """
     dp = dp_axes(mesh)
-    if cfg.pure_dp:
-        dp = tuple(mesh.axis_names)
     dpn = int(np.prod([mesh.shape[a] for a in dp]))
     m = mesh.shape["model"]
-
-    model_free = not cfg.pure_dp  # pure_dp spends "model" on the batch axis
 
     def kv(x):
         l, b, s, hkv, hd = x.shape
@@ -281,9 +271,9 @@ def cache_shardings(cfg: ModelConfig, mesh, cache_shapes: dict) -> dict:
             spec[1] = dp
         elif _div(s, dpn):
             spec[2] = dp
-        if model_free and _div(hkv, m):
+        if _div(hkv, m):
             spec[3] = "model"
-        elif model_free and spec[2] is None and _div(s, m):
+        elif spec[2] is None and _div(s, m):
             # GQA archs with kv-heads < model axis: shard the KV sequence
             # instead (flash-decoding-style split-K) — removes both the
             # replicated-cache memory and the redundant attention compute
@@ -295,7 +285,7 @@ def cache_shardings(cfg: ModelConfig, mesh, cache_shapes: dict) -> dict:
         spec: list = [None] * 5
         if _div(b, dpn):
             spec[1] = dp
-        if model_free and _div(h, m):
+        if _div(h, m):
             spec[2] = "model"
         return NamedSharding(mesh, P(*spec))
 
@@ -304,7 +294,7 @@ def cache_shardings(cfg: ModelConfig, mesh, cache_shapes: dict) -> dict:
         spec: list = [None] * 4
         if _div(b, dpn):
             spec[1] = dp
-        if model_free and _div(c, m):
+        if _div(c, m):
             spec[3] = "model"
         return NamedSharding(mesh, P(*spec))
 

@@ -15,9 +15,9 @@ Every transmit path in the framework is the same five-stage pipeline —
   * ``ENCODE_STAGES`` — wire byte recoding ('identity', 'sign_magnitude').
   * ``PACK_STAGES``   — flit layout: 'row' (row-major), 'lane' (the PSU's
     lane-major packing, paper Fig. 2), 'col' (whole-stream column-major —
-    the layout under which row ordering has leverage, EXPERIMENTS.md
-    §Arch-BT).  'col' is a stream layout only; the paired per-packet framing
-    uses 'row'/'lane'.
+    the layout under which row ordering has leverage, measured by
+    ``benchmarks/arch_bt.py``).  'col' is a stream layout only; the paired
+    per-packet framing uses 'row'/'lane'.
 
 The legacy strategy API (``make_order`` / ``order_packets`` /
 ``ORDER_STRATEGIES``) is preserved on top of the registries; the old import
@@ -74,7 +74,7 @@ def lookup_stage(kind: str, name: str, registry: Mapping[str, object]):
 def to_sign_magnitude(q_int8: jax.Array) -> jax.Array:
     """Recode two's-complement int8 as sign-magnitude bytes.
 
-    Beyond-paper optimization (EXPERIMENTS.md §Arch-BT): two's complement
+    Beyond-paper optimization (``benchmarks/arch_bt.py``): two's complement
     decorrelates popcount from magnitude (-1 = 0xFF has popcount 8), which
     both halves the ordering signal and inflates baseline BT.  Sign-magnitude
     makes popcount monotone in |value| — near-zero weights become near-zero

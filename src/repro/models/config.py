@@ -107,30 +107,21 @@ class ModelConfig:
     n_enc_layers: int = 0
     # vlm: leading positions of the sequence are precomputed patch embeddings
     n_frontend_tokens: int = 0
-    # numerics / performance knobs (see EXPERIMENTS.md §Perf)
+    # numerics / performance knobs
     dtype: str = "bfloat16"  # activation/compute dtype
     param_dtype: str = "float32"
     attn_impl: Literal["dense", "chunked", "chunked_skip"] = "chunked_skip"
     attn_chunk: int = 1024
-    remat: bool = True
-    # "full": recompute everything in backward (min memory, re-runs the TP
-    # collectives).  "save_block_io": save the all-reduced attn/mlp outputs
-    # so backward recompute skips the forward collectives (§Perf lever —
-    # trades ~2 x (B,S,d) bytes/layer for ~1/3 of the all-reduce wire)
-    remat_policy: Literal["full", "save_block_io"] = "full"
+    remat: bool = True  # recompute each layer's activations in backward
     logits_chunk: int = 0  # 0 = unchunked; >0 = sequence-chunked loss
     scan_layers: bool = True
     # FSDP (ZeRO-3-style): additionally shard params/optimizer over the
     # "data" axis for training — required for archs whose fp32 params +
     # Adam state exceed HBM under TP-only sharding (qwen3-moe, internvl2)
     fsdp: bool = False
-    # pure data parallelism: replicate ALL params and shard the batch over
-    # every mesh axis (incl. "model").  The right regime for small models
-    # whose TP collectives dominate (mamba2-370m: §Perf iteration A1)
-    pure_dp: bool = False
     # ZeRO-1: shard Adam m/v over the "data" axis (params keep their TP
     # sharding; GSPMD inserts the post-update weight all-gather).  Frees
-    # 8 bytes/param of replicated state at low-TP mesh ratios (§Perf C6)
+    # 8 bytes/param of replicated state at low-TP mesh ratios
     zero1: bool = False
 
     @property

@@ -8,7 +8,7 @@ Attention supports three implementations (config.attn_impl):
   chunked      -- lax.scan over query chunks, online softmax over all KV
                   chunks with causal masking (memory-bound, 2x causal FLOPs).
   chunked_skip -- statically unrolled query-chunk loop that *skips* KV chunks
-                  above the causal diagonal (FLOP-optimal; the §Perf default).
+                  above the causal diagonal (FLOP-optimal; the default).
 """
 
 from __future__ import annotations
@@ -193,8 +193,8 @@ def _sdpa_chunked(
     skip=True statically unrolls the query loop and skips KV chunks above the
     causal diagonal (FLOP-optimal); skip=False lax.scans over query chunks
     with full masked KV (compact HLO, 2x causal FLOPs).  ``unroll`` unrolls
-    the scans for the dry-run cost pass (XLA cost analysis visits loop
-    bodies once — see repro.launch.dryrun).
+    the scans so XLA's cost analysis, which visits loop bodies once,
+    counts every iteration.
     """
     b, sq, h, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]

@@ -2,7 +2,7 @@
 
 Parameters are plain pytrees with layer-stacked leaves (leading axis L) so
 layer application is a ``lax.scan`` — compile time and HLO size stay flat in
-depth (crucial for 48-layer x 512-device dry-runs).  ``jax.checkpoint`` wraps
+depth.  ``jax.checkpoint`` wraps
 the scan body when ``cfg.remat`` (activation recomputation).
 
 Families:
@@ -25,7 +25,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.ad_checkpoint import checkpoint_name
 
 from repro import _obs_hooks
 
@@ -115,7 +114,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 
 
 def param_shapes(cfg: ModelConfig) -> Params:
-    """Abstract init (no allocation) — dry-run input."""
+    """Abstract init (no allocation): the parameter tree's shapes."""
     return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
 
 
@@ -124,32 +123,25 @@ def param_shapes(cfg: ModelConfig) -> Params:
 # --------------------------------------------------------------------------
 
 
-def _tag(x: jax.Array, name: str) -> jax.Array:
-    """checkpoint_name tag: inert under remat_policy="full"; with
-    "save_block_io" these (all-reduced) tensors are saved, so backward
-    recompute does not re-run the forward TP collectives."""
-    return checkpoint_name(x, name)
-
-
 def _attn_layer(lp: Params, h: jax.Array, cfg: ModelConfig, positions, causal=True):
     a = attention(lp["attn"], rms_norm(h, lp["attn_norm"], cfg.rms_eps), cfg, positions, causal)
-    h = h + _tag(a, "attn_out")
+    h = h + a
     m = mlp(lp["mlp"], rms_norm(h, lp["mlp_norm"], cfg.rms_eps), cfg)
-    return h + _tag(m, "mlp_out")
+    return h + m
 
 
 def _moe_layer(lp: Params, h: jax.Array, cfg: ModelConfig, positions,
                return_route: bool = False):
     a = attention(lp["attn"], rms_norm(h, lp["attn_norm"], cfg.rms_eps), cfg, positions, True)
-    h = h + _tag(a, "attn_out")
+    h = h + a
     out = moe_block(lp["moe"], rms_norm(h, lp["mlp_norm"], cfg.rms_eps), cfg,
                     return_route=return_route)
-    return (h + _tag(out[0], "mlp_out"), *out[1:])
+    return (h + out[0], *out[1:])
 
 
 def _ssm_layer(lp: Params, h: jax.Array, cfg: ModelConfig):
     y = ssd_block(lp["ssd"], rms_norm(h, lp["norm"], cfg.rms_eps), cfg)
-    return h + _tag(y, "mlp_out")
+    return h + y
 
 
 def _dec_layer(lp: Params, h: jax.Array, ek: jax.Array, ev: jax.Array, cfg, positions):
@@ -161,20 +153,14 @@ def _dec_layer(lp: Params, h: jax.Array, ek: jax.Array, ev: jax.Array, cfg, posi
 
 
 def _scan(cfg: ModelConfig, body, carry, xs):
-    """lax.scan honoring cfg.scan_layers: the dry-run unrolls so XLA's
-    cost_analysis (which visits while bodies ONCE) reports true totals."""
+    """lax.scan honoring cfg.scan_layers: ``scan_layers=False`` unrolls so
+    XLA's cost_analysis (which visits while bodies ONCE) reports true
+    totals."""
     return lax.scan(body, carry, xs, unroll=not cfg.scan_layers)
 
 
 def _maybe_remat(fn, cfg: ModelConfig):
-    if not cfg.remat:
-        return fn
-    if cfg.remat_policy == "save_block_io":
-        policy = jax.checkpoint_policies.save_only_these_names(
-            "attn_out", "mlp_out"
-        )
-        return jax.checkpoint(fn, policy=policy)
-    return jax.checkpoint(fn)
+    return jax.checkpoint(fn) if cfg.remat else fn
 
 
 # --------------------------------------------------------------------------

@@ -15,7 +15,7 @@ Distributed-optimization tricks for the ICI collective term (DESIGN.md §5):
     ``repro.link`` TX pipeline (DESIGN.md §8).
 
 These run inside ``shard_map`` over the data axes, where the wire format is
-explicit; the GSPMD path (default dry-run) keeps implicit fp32 all-reduce.
+explicit; the GSPMD path keeps implicit fp32 all-reduce.
 """
 
 from __future__ import annotations

@@ -26,8 +26,8 @@ Three integration points, all exploiting order-insensitive accumulation:
 
   3. **BT accounting** (`stream_bt_report`): models any tensor as a 128-bit
      flit stream and measures bit transitions before/after ordering via a
-     ``repro.link.TxPipeline`` row-stream measurement — this is what feeds
-     the link-energy column of the roofline report.
+     ``repro.link.TxPipeline`` row-stream measurement — this is what
+     ``benchmarks/arch_bt.py`` reports.
 """
 
 from __future__ import annotations
@@ -249,7 +249,7 @@ def stream_bt_report(
     only touches row-boundary flits).  ``layout="col"`` interleaves rows
     column-major so consecutive flits carry *adjacent rows in the sorted
     order* — the layout under which row ordering has leverage (see the
-    measured trade-off in EXPERIMENTS.md §Arch-BT).
+    measured trade-off in ``benchmarks/arch_bt.py``).
 
     Implemented as two ``repro.link.TxPipeline`` row-stream measurements
     (baseline spec with key='none', ordered spec as configured).

@@ -6,7 +6,7 @@
 ``repro.launch``) or none (CPU smoke tests).
 
 Gradient accumulation (``microbatches > 1``) lax.scans over batch slices,
-trading activation memory for steps — one of the §Perf memory levers.
+trading activation memory for steps.
 """
 
 from __future__ import annotations
@@ -75,7 +75,7 @@ def make_train_step(
                 body,
                 (zeros, jnp.float32(0.0)),
                 jax.tree.map(reshape_mb, batch),
-                unroll=not cfg.scan_layers,  # dry-run cost pass unrolls
+                unroll=not cfg.scan_layers,  # unrolled for XLA cost analysis
             )
             grads = jax.tree.map(lambda g: g / microbatches, grads)
             loss = loss / microbatches

@@ -2,8 +2,8 @@ import os
 import sys
 import types
 
-# Tests must see the real (single) CPU device — the 512-device flag belongs
-# to the dry-run entry point ONLY (repro/launch/dryrun.py).
+# Tests must see the real (single) CPU device: a test that needs several
+# host devices sets the flag in its own subprocess (tests/test_distributed.py).
 assert "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
 
 try:
@@ -54,3 +54,11 @@ except ModuleNotFoundError:
     _hyp.assume = _strategy_stub
     sys.modules["hypothesis"] = _hyp
     sys.modules["hypothesis.strategies"] = _st
+
+
+def pytest_configure(config):
+    # register the marker so pytest does not warn about it; no run
+    # deselects it
+    config.addinivalue_line(
+        "markers", "slow: a test that takes tens of seconds or more"
+    )

@@ -6,8 +6,8 @@ any traced computation), so every kernel entry point's traced jaxpr is
 byte-identical whether ``repro.obs`` is absent from the process, imported
 but inactive, or actively collecting.  The rest pins the probe
 vocabulary, the metrics JSON round-trip, the per-link report against
-``NocReport``, the Chrome trace schema, and the ``check_bench``
-regression gate.
+``NocReport``, the Chrome trace schema, and the report CLI's
+``--trace`` artifact.
 """
 
 import json
@@ -368,112 +368,36 @@ def test_tracer_chrome_schema(tmp_path):
     assert json.load(open(tmp_path / "t.json")) == out
 
 
-# ------------------------------------------------- check_bench gating
-
-
-def _write_bench(dirpath, name, wall_s, tiny=True, failed=None):
-    payload = {
-        "module": name, "tiny": tiny, "wall_s": wall_s,
-        "rows": [] if failed else [
-            {"name": f"{name}/r0", "us_per_call": 1.0, "derived": "ok"}
-        ],
-    }
-    if failed:
-        payload["failed"] = failed
-    os.makedirs(dirpath, exist_ok=True)
-    with open(os.path.join(dirpath, f"BENCH_{name}.json"), "w") as f:
-        json.dump(payload, f)
-
-
-def test_check_bench_gates(tmp_path):
-    from benchmarks.check_bench import check
-    from benchmarks.run import MODULES
-
-    run_dir, base_dir = str(tmp_path / "run"), str(tmp_path / "base")
-    for name in MODULES:
-        _write_bench(run_dir, name, wall_s=1.0)
-        _write_bench(base_dir, name, wall_s=1.0)
-    problems, warnings = check(run_dir, base_dir)
-    assert problems == [] and warnings == []
-
-    # a registered module that wrote no JSON fails by name
-    os.remove(os.path.join(run_dir, f"BENCH_{MODULES[0]}.json"))
-    problems, _ = check(run_dir, base_dir)
-    assert len(problems) == 1 and MODULES[0] in problems[0]
-    _write_bench(run_dir, MODULES[0], wall_s=1.0)
-
-    # a module dropped from MODULES but still in the baseline fails by name
-    _write_bench(base_dir, "ghost_module", wall_s=1.0)
-    problems, _ = check(run_dir, base_dir)
-    assert len(problems) == 1
-    assert "ghost_module" in problems[0] and "dropped" in problems[0]
-    os.remove(os.path.join(base_dir, "BENCH_ghost_module.json"))
-
-    # wall regression: >2x AND >1s fails; >1.25x AND >0.25s warns
-    _write_bench(run_dir, MODULES[1], wall_s=4.0)
-    problems, _ = check(run_dir, base_dir)
-    assert len(problems) == 1 and "regression" in problems[0]
-    _write_bench(run_dir, MODULES[1], wall_s=1.6)
-    problems, warnings = check(run_dir, base_dir)
-    assert problems == []
-    assert len(warnings) == 1 and MODULES[1] in warnings[0]
-
-    # sub-second smoke noise never fails on ratio alone
-    _write_bench(run_dir, MODULES[1], wall_s=0.3)
-    _write_bench(base_dir, MODULES[1], wall_s=0.1)
-    problems, warnings = check(run_dir, base_dir)
-    assert problems == [] and warnings == []
-    _write_bench(run_dir, MODULES[1], wall_s=1.0)
-    _write_bench(base_dir, MODULES[1], wall_s=1.0)
-
-    # a failed module is reported once, not also wall-gated
-    _write_bench(run_dir, MODULES[2], wall_s=99.0, failed="FAILED: boom")
-    problems, _ = check(run_dir, base_dir)
-    assert len(problems) == 1 and "boom" in problems[0]
-    _write_bench(run_dir, MODULES[2], wall_s=1.0)
-
-    # tiny-flag mismatch skips the wall gate with a warning
-    _write_bench(run_dir, MODULES[3], wall_s=99.0, tiny=False)
-    problems, warnings = check(run_dir, base_dir)
-    assert problems == []
-    assert any("tiny" in w for w in warnings)
-
-    # no baseline at all: presence still gates, wall gate skipped
-    problems, warnings = check(run_dir, str(tmp_path / "nope"))
-    assert problems == []
-    assert any("skipped" in w for w in warnings)
-
-
 # ------------------------------------------- bench --trace end to end
 
 
 @pytest.mark.slow
 def test_bench_trace_artifact(tmp_path):
-    """One tiny dse_sweep run under --json --trace: the BENCH json carries
-    provenance, the TRACE json is Chrome-loadable with >=95% of the module
-    wall covered by spans (the DESIGN.md §14 acceptance bar)."""
+    """One tiny dse_sweep run under --trace: the TRACE json is
+    Chrome-loadable, carries the run's provenance in its metadata, and
+    covers >=95% of the module's run with spans (the DESIGN.md §14
+    acceptance bar)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join([os.path.join(_REPO, "src"), _REPO])
     env.setdefault("JAX_PLATFORMS", "cpu")
     env["REPRO_BENCH_TINY"] = "1"
     env["REPRO_DSE_ARTIFACT"] = str(tmp_path / "dse_front.json")
     out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.run", "--json", "--trace",
-         "dse_sweep"],
+        [sys.executable, "-m", "benchmarks.run", "--trace", "dse_sweep"],
         capture_output=True, text=True, env=env, cwd=str(tmp_path),
         timeout=600,
     )
     assert out.returncode == 0, out.stderr
-
-    bench = json.load(open(tmp_path / "BENCH_dse_sweep.json"))
-    for field in ("git_sha", "timestamp", "jax_version"):
-        assert bench.get(field), f"missing provenance field {field!r}"
-    assert "T" in bench["timestamp"]  # ISO-8601
-    assert any("dse/obs/" in r["name"] for r in bench["rows"])
+    lines = out.stdout.splitlines()
+    assert lines[0] == "name,derived"
+    assert any(line.startswith("dse/obs/") for line in lines[1:])
 
     trace = json.load(open(tmp_path / "TRACE_dse_sweep.json"))
     assert isinstance(trace["traceEvents"], list) and trace["traceEvents"]
     meta = trace["metadata"]
+    for field in ("git_sha", "timestamp", "jax_version"):
+        assert meta.get(field), f"missing provenance field {field!r}"
+    assert "T" in meta["timestamp"]  # ISO-8601
     assert meta["module"] == "dse_sweep"
     assert meta["span_coverage"] >= 0.95
     spans = [e for e in trace["traceEvents"] if e["ph"] == "X"]

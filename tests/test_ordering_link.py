@@ -81,7 +81,7 @@ def test_paired_stream_shape_and_split():
 def test_acc_reduces_bt_on_uniform_paired_traffic():
     """Even on uniform random bytes, popcount ordering with lane packing
     reduces input-side BT (the E[HD | same popcount] = 3.5 < 4 effect —
-    see EXPERIMENTS.md §Table I analysis)."""
+    see benchmarks/datagen.py)."""
     inp, wgt = rand_packets(2000, 32, 5), rand_packets(2000, 32, 6)
     base = measure(inp, wgt, strategy="none")
     acc = measure(inp, wgt, strategy="acc")

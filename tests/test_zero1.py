@@ -1,4 +1,4 @@
-"""ZeRO-1 optimizer-state sharding rules (§Perf C6 lever)."""
+"""ZeRO-1 optimizer-state sharding rules."""
 
 import jax
 import numpy as np
