@@ -180,7 +180,9 @@ def fleet_decode_flows(
 
     Payloads are deterministic strided slices of ``weights``'s int8 wire
     image (tiled if the tensor is smaller than one flow), so every flow
-    carries distinct but reproducible bytes.
+    carries distinct but reproducible bytes.  They are host arrays: one
+    gather on the device cuts every slice, and one transfer brings them
+    over for ``FlowBatch`` to stack.
     """
     if spec.weight_lanes:
         raise ValueError(
@@ -199,11 +201,20 @@ def fleet_decode_flows(
             f"{shards} shards need {shards} PE columns; a {topo.rows}x"
             f"{topo.cols} grid has {pe_cols} (column 0 is the memory router)"
         )
-    data = _wire_bytes(weights)
     need = packets_per_flow * spec.elems_per_packet
-    if int(data.size) < need:
-        data = jnp.tile(data, -(-need // int(data.size)))
-    span = int(data.size) - need  # highest valid slice start
+    n = users * layers * shards
+    with _obs.span("fleet.route", users=users, layers=layers, shards=shards,
+                   flows=n, bytes=n * need):
+        data = _wire_bytes(weights)
+        if int(data.size) < need:
+            data = jnp.tile(data, -(-need // int(data.size)))
+        span = int(data.size) - need  # highest valid slice start
+        # flow fi = (u * layers + layer) * shards + s starts at a coprime
+        # stride, which walks the wire image without re-slicing the same
+        # window for co-located tenants
+        offs = np.arange(n, dtype=np.int64) * 7919 % (span + 1)
+        index = (offs[:, None] + np.arange(need)).astype(np.int32)
+        staged = np.asarray(jnp.take(data, jnp.asarray(index)))
     flows = []
     for u in range(users):
         row = u % topo.rows
@@ -216,15 +227,12 @@ def fleet_decode_flows(
                     topo.router(row, 1 + c) for c in range(lo, hi)
                 )
                 fi = (u * layers + layer) * shards + s
-                # coprime stride walks the wire image without re-slicing
-                # the same window for co-located tenants
-                off = 0 if span == 0 else (fi * 7919) % (span + 1)
                 flows.append(
                     TrafficFlow(
                         name=f"u{u}/l{layer}/s{s}",
                         src=mem,
                         dsts=dsts,
-                        inputs=data[off : off + need].reshape(
+                        inputs=staged[fi].reshape(
                             packets_per_flow, spec.elems_per_packet
                         ),
                     )

@@ -38,6 +38,9 @@ probe vocabulary:
   link.activity      event  one per link measured with wire-level
                             activity (``activity_windows=``) — per-wire
                             toggle telemetry (DESIGN.md §15)
+  fleet.route        span   ``noc.fleet_decode_flows`` cutting every
+                            flow's payload in one device gather and
+                            bringing them to the host
   moe.route          span   ``noc.moe_dispatch_flows`` grouping the
                             tokens by (source chip, expert) and bringing
                             the grouped rows to the host
@@ -105,6 +108,7 @@ PROBE_KINDS: dict[str, str] = {
     "noc.stack": "span",
     "noc.measure": "span",
     "noc.rollup": "span",
+    "fleet.route": "span",
     "moe.route": "span",
     "moe.load": "event",
     "noc.link": "event",
@@ -132,6 +136,7 @@ _SPAN_LABELS: dict[str, tuple[str, ...]] = {
     "noc.stack": (),
     "noc.measure": (),
     "noc.rollup": (),
+    "fleet.route": ("users", "shards"),
     "moe.route": ("chips", "experts"),
     "dse.measure": ("width",),
     "dse.readback": ("width",),

@@ -198,6 +198,66 @@ def test_fleet_decode_flows_shapes_and_validation():
                            spec=spec)  # > PE columns
 
 
+def _sliced_payloads(data, need, flows):
+    """The per-flow definition of the fleet payloads: the wire image
+    (tiled when shorter than one flow) cut at ``(fi * 7919) mod (span +
+    1)``, one slice per flow."""
+    if data.size < need:
+        data = np.tile(data, -(-need // data.size))
+    span = data.size - need
+    offs = [0 if span == 0 else (fi * 7919) % (span + 1)
+            for fi in range(flows)]
+    return data, offs
+
+
+@pytest.mark.parametrize("scale", [5.3, 0.7, 1.0],
+                         ids=["strided", "tiled", "span0"])
+def test_fleet_decode_flows_payloads_are_strided_slices(scale):
+    """Every flow carries its strided slice of the wire image, byte for
+    byte, as a host view of one staged array (not a device slice)."""
+    topo = mesh(4, 5)
+    spec = LinkSpec(input_lanes=16, weight_lanes=0)
+    need = 2 * spec.elems_per_packet
+    data = np.random.default_rng(11).integers(
+        0, 256, int(scale * need), dtype=np.uint8)
+    flows = fleet_decode_flows(
+        jnp.asarray(data), topo, users=3, layers=2, shards=2, spec=spec)
+    tiled, offs = _sliced_payloads(data, need, len(flows))
+    assert (len(set(offs)) > 1) == (scale != 1.0)
+    for f, off in zip(flows, offs):
+        np.testing.assert_array_equal(
+            f.inputs, tiled[off:off + need].reshape(2, -1))
+    staged = flows[0].inputs.base
+    assert isinstance(staged, np.ndarray)
+    assert staged.shape == (len(flows), need)
+    for f in flows:
+        assert isinstance(f.inputs, np.ndarray) and f.inputs.base is staged
+        assert np.shares_memory(f.inputs, staged)
+
+
+@pytest.mark.parametrize("key", ["none", "acc", "app"])
+def test_fleet_staged_flows_match_device_slices(key):
+    """The staged payloads price every link exactly as the same flows cut
+    one device slice each."""
+    topo = mesh(4, 5)
+    spec = LinkSpec(input_lanes=16, weight_lanes=0, key=key)
+    need = 2 * spec.elems_per_packet
+    data = np.random.default_rng(5).integers(
+        0, 256, 40 * need + 7, dtype=np.uint8)
+    staged = fleet_decode_flows(
+        jnp.asarray(data), topo, users=3, layers=2, shards=2, spec=spec)
+    wire, offs = _sliced_payloads(data, need, len(staged))
+    wire = jnp.asarray(wire)
+    sliced = [
+        TrafficFlow(f.name, f.src, f.dsts,
+                    wire[off:off + need].reshape(2, -1))
+        for f, off in zip(staged, offs)
+    ]
+    got = simulate_noc(topo, staged, spec, sort_at="source")
+    want = simulate_noc(topo, sliced, spec, sort_at="source")
+    assert got.links == want.links and got.total_bt > 0
+
+
 # ------------------------------------------------- the contention model
 
 

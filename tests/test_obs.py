@@ -305,6 +305,23 @@ def test_noc_host_phase_spans_under_profiling(monkeypatch):
     assert "kernel.dispatch" in entered
 
 
+def test_fleet_route_span_counts_one_staging_per_call():
+    """``fleet_decode_flows`` stages every payload inside one
+    ``fleet.route`` span, labeled by users and shards; the flow count
+    and bytes stay trace-only."""
+    from repro.noc import fleet_decode_flows
+
+    assert obs.PROBE_KINDS["fleet.route"] == "span"
+    spec = LinkSpec(input_lanes=16, weight_lanes=0)
+    with obs.collect() as reg:
+        flows = fleet_decode_flows(
+            _packets(4, 256, seed=5).reshape(-1), mesh(4, 5), users=3,
+            layers=2, shards=2, spec=spec)
+    assert len(flows) == 12
+    assert reg.value("fleet.route.calls", users=3, shards=2) == 1
+    assert reg.value("fleet.route.calls", users=3, shards=2, flows=12) == 0
+
+
 def test_top_links_ordering_and_format(tmp_path):
     reg, rep = _noc_run()
     top = obs.top_links(reg, 2)
